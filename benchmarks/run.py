@@ -70,6 +70,9 @@ def main() -> None:
                          "writes artifacts/bench/trace.json (Perfetto) and "
                          "artifacts/bench/ttft_breakdown.csv")
     args = ap.parse_args()
+    from repro.core.jaxutil import use_compile_cache
+
+    use_compile_cache()
     if args.profile:
         from repro.sim.engine import enable_profiling
         enable_profiling(True)

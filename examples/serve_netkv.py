@@ -1,7 +1,8 @@
 """End-to-end driver: serve a small real model with batched requests through
 the disaggregated cluster — prefill engines, NetKV routing, kv_pack transfer,
 continuous-batching decode.  Token output is exact (tested against a
-monolithic forward).
+monolithic forward).  This runs qwen3-14b's float32 smoke config, sized for
+a CPU; ``python -m repro.launch.serve --real`` serves a full-width model.
 
     PYTHONPATH=src python examples/serve_netkv.py
 """

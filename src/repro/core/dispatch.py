@@ -281,13 +281,10 @@ class CohortSelector:
         rows; snapshot free/healthy + the kernel's f32 feasibility threshold
         so later rows can prove their precomputed argmin is still live."""
         from repro.kernels.netkv_score import netkv_score_cohort
+        from repro.kernels.ops import interpret_mode
 
         sched, cv, oracle = self._sched, self._cv, self._oracle
         infl = self._inflight if sched.uses_self_contention else None
-        if sched._pallas_interpret is None:
-            import jax
-
-            sched._pallas_interpret = jax.default_backend() != "tpu"
         cong = sched._congestion_by_tier(oracle)
         items = [self._items[int(k)] for k in rows]
         tier_rows = np.stack([cv.tier_row(it.prefill_id) for it in items])
@@ -305,7 +302,7 @@ class CohortSelector:
             input_len=[it.req.input_len for it in items],
             iter_a=sched.iter_model.a, iter_b=sched.iter_model.b,
             m_min=sched.m_min, beta_max=sched.beta_max,
-            interpret=sched._pallas_interpret,
+            interpret=interpret_mode(),
         )
         self._pl_rows = {int(k): i for i, k in enumerate(rows)}
         self._pl_costs = np.asarray(costs)

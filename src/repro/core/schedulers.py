@@ -469,15 +469,13 @@ class NetKVFull(Scheduler):
     uses_self_contention = True
     uses_congestion = True
 
-    def __init__(self, *args, backend: str = "numpy",
-                 pallas_interpret: bool | None = None, **kwargs):
+    def __init__(self, *args, backend: str = "numpy", **kwargs):
         super().__init__(*args, **kwargs)
         if backend not in ("numpy", "pallas"):
             raise ValueError(f"unknown scoring backend {backend!r}")
         if backend == "pallas" and self.iter_model.breaks:
             raise ValueError("pallas backend supports linear iter models only")
         self.backend = backend
-        self._pallas_interpret = pallas_interpret
 
     def select(self, req, prefill_id, cands, oracle, inflight=None):
         cv = as_cluster_view(cands, oracle)
@@ -514,11 +512,8 @@ class NetKVFull(Scheduler):
     # -- Pallas scoring path ------------------------------------------------
     def _select_pallas(self, req, prefill_id, cv, oracle, inflight, s_eff, tier_row):
         from repro.kernels.netkv_score import BIG, netkv_score
+        from repro.kernels.ops import interpret_mode
 
-        if self._pallas_interpret is None:
-            import jax
-
-            self._pallas_interpret = jax.default_backend() != "tpu"
         cong = self._congestion_by_tier(oracle)
         nfl = self._n_by_tier(inflight, prefill_id)
         costs, best = netkv_score(
@@ -532,7 +527,7 @@ class NetKVFull(Scheduler):
             s_r=float(req.kv_bytes), input_len=float(req.input_len),
             iter_a=self.iter_model.a, iter_b=self.iter_model.b,
             m_min=self.m_min, beta_max=self.beta_max,
-            interpret=self._pallas_interpret,
+            interpret=interpret_mode(),
         )
         j = int(best)
         best_cost = float(costs[j])

@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax moved TPUCompilerParams -> CompilerParams across releases;
-# resolve whichever this version ships.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -102,7 +98,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

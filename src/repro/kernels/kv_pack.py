@@ -14,8 +14,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# Block indices typed int32: a bare 0 turns int64 under jax_enable_x64,
+# which Mosaic refuses in an index_map.
+_I0 = np.int32(0)
 
 
 def _pack_kernel(idx_ref, pool_ref, out_ref):
@@ -38,10 +43,10 @@ def kv_pack(pool: jax.Array, block_table: jax.Array, *,
             grid=(n_sel,),
             in_specs=[
                 pl.BlockSpec((1, page_tokens, kv, dh),
-                             lambda i, idx: (idx[i], 0, 0, 0)),
+                             lambda i, idx: (idx[i], _I0, _I0, _I0)),
             ],
             out_specs=pl.BlockSpec((1, page_tokens, kv, dh),
-                                   lambda i, idx: (i, 0, 0, 0)),
+                                   lambda i, idx: (i, _I0, _I0, _I0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_sel, page_tokens, kv, dh), pool.dtype),
         interpret=interpret,
@@ -66,11 +71,11 @@ def kv_unpack(pool: jax.Array, buf: jax.Array, block_table: jax.Array, *,
             num_scalar_prefetch=1,
             grid=(n_sel,),
             in_specs=[
-                pl.BlockSpec((1, page_tokens, kv, dh), lambda i, idx: (idx[i], 0, 0, 0)),
-                pl.BlockSpec((1, page_tokens, kv, dh), lambda i, idx: (i, 0, 0, 0)),
+                pl.BlockSpec((1, page_tokens, kv, dh), lambda i, idx: (idx[i], _I0, _I0, _I0)),
+                pl.BlockSpec((1, page_tokens, kv, dh), lambda i, idx: (i, _I0, _I0, _I0)),
             ],
             out_specs=pl.BlockSpec((1, page_tokens, kv, dh),
-                                   lambda i, idx: (idx[i], 0, 0, 0)),
+                                   lambda i, idx: (idx[i], _I0, _I0, _I0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_pages, page_tokens, kv, dh), buf.dtype),
         input_output_aliases={1: 0},

@@ -16,11 +16,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BIG = 3.0e38
+# Block indices typed int32: a bare 0 turns int64 under jax_enable_x64,
+# which Mosaic refuses in an index_map.
+_I0 = np.int32(0)
 
 
 def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
@@ -56,38 +60,49 @@ def _score_cohort_kernel(scal_ref, free_ref, queued_ref, batch_ref, hit_ref,
     The per-row scalars deliberately arrive as a *block* rather than as
     ``scal_ref[base + program_id]``: a traced gather index changes XLA's
     fusion/FMA decisions for everything downstream, which costs bit-parity
-    across cohort sizes (observed as 1-ulp cost drift off-TPU)."""
-    s_r = rscal_ref[0, 0]
-    l_r = rscal_ref[0, 1]
+    across cohort sizes (observed as 1-ulp cost drift off-TPU).
+
+    Rowed operands are ``(rows, 1, lanes)`` with ``(1, 1, lanes)`` blocks:
+    Mosaic needs a block's last two dims to tile (8, 128) or to equal the
+    array's, which a ``(1, lanes)`` block of an ``(rows, lanes)`` array does
+    not.  Every constant is an explicit f32 so the kernel lowers the same
+    whether or not ``jax_enable_x64`` is on."""
+    f32 = jnp.float32
+    one, zero = f32(1.0), f32(0.0)
+    s_r = rscal_ref[0, 0, 0]
+    l_r = rscal_ref[0, 0, 1]
     iter_a = scal_ref[0]
     iter_b = scal_ref[1]
     m_min = scal_ref[2]
     beta_max = scal_ref[3]
 
-    hit = jnp.minimum(hit_ref[...], l_r)
-    s_eff = s_r * (1.0 - hit / jnp.maximum(l_r, 1.0))                    # Eq. (2)
+    hit = jnp.minimum(hit_ref[0], l_r)
+    s_eff = s_r * (one - hit / jnp.maximum(l_r, one))                    # Eq. (2)
 
-    tier = tier_ref[...]
+    tier = tier_ref[0]
     beff = jnp.zeros_like(s_eff)
     lat = jnp.zeros_like(s_eff)
     for t in range(4):
-        sel = (tier == t).astype(jnp.float32)
-        bt = bw_ref[0, t] * (1.0 - cong_ref[0, t]) / (1.0 + infl_ref[0, t])  # Eq. (4)
+        sel = (tier == t).astype(f32)
+        bt = bw_ref[0, t] * (one - cong_ref[0, t]) / (one + infl_ref[0, 0, t])  # Eq. (4)
         beff = beff + sel * bt
         lat = lat + sel * lat_ref[0, t]
-    t_xfer = s_eff / jnp.maximum(beff, 1e-9) + lat                       # Eq. (3)
+    t_xfer = s_eff / jnp.maximum(beff, f32(1e-9)) + lat                  # Eq. (3)
 
-    t_iter = (iter_a + iter_b * batch_ref[...]) * scale_ref[...]
-    blocked = jnp.maximum(0.0, queued_ref[...] - (beta_max - batch_ref[...]))
+    batch = batch_ref[...]
+    scale = scale_ref[...]
+    t_iter = (iter_a + iter_b * batch) * scale
+    blocked = jnp.maximum(zero, queued_ref[...] - (beta_max - batch))
     t_queue = blocked * t_iter                                           # Eq. (6)
-    t_dec = (iter_a + iter_b * (batch_ref[...] + 1.0)) * scale_ref[...]  # Eq. (7)
+    t_dec = (iter_a + iter_b * (batch + one)) * scale                    # Eq. (7)
 
     cost = t_xfer + t_queue + t_dec                                      # Eq. (5)
     lane = jax.lax.broadcasted_iota(jnp.int32, cost.shape, 1)
-    feasible = (healthy_ref[...] > 0.5) & (free_ref[...] >= s_eff + m_min) & (lane < n_real)
-    cost = jnp.where(feasible, cost, BIG)
-    cost_ref[...] = cost
-    best_ref[0, 0] = jnp.argmin(cost[0]).astype(jnp.int32)
+    feasible = ((healthy_ref[...] > f32(0.5)) & (free_ref[...] >= s_eff + m_min)
+                & (lane < jnp.int32(n_real)))
+    cost = jnp.where(feasible, cost, f32(BIG))
+    cost_ref[0] = cost
+    best_ref[0, 0, 0] = jax.lax.argmin(cost[0], 0, jnp.int32)
 
 
 def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
@@ -139,42 +154,47 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
             x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
         return x.reshape(-1, dp)
 
-    scal = jnp.asarray([iter_a, iter_b, m_min, float(beta_max)], jnp.float32)
-    rscal = jnp.stack([s_rv, l_rv, jnp.zeros(r, jnp.float32),
-                       jnp.zeros(r, jnp.float32)], axis=1)
+    def rowed(x, dtype=jnp.float32):
+        return prep(x, dtype).reshape(r, 1, dp)
+
+    f32 = jnp.float32
+    scal = jnp.stack([jnp.asarray(v, f32) for v in
+                      (iter_a, iter_b, m_min, float(beta_max))])
+    rscal = jnp.stack([s_rv, l_rv, jnp.zeros(r, f32), jnp.zeros(r, f32)],
+                      axis=1).reshape(r, 1, 4)
     kernel = functools.partial(_score_cohort_kernel, n_real=d)
-    shared = pl.BlockSpec((1, dp), lambda i, s: (0, 0))
-    rowed = pl.BlockSpec((1, dp), lambda i, s: (i, 0))
+    shared = pl.BlockSpec((1, dp), lambda i, s: (_I0, _I0))
+    row_blk = pl.BlockSpec((1, 1, dp), lambda i, s: (i, _I0, _I0))
+    row4 = pl.BlockSpec((1, 1, 4), lambda i, s: (i, _I0, _I0))
     costs, best = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(r,),
-            in_specs=[shared, shared, shared, rowed, rowed, shared, shared]
-            + [pl.BlockSpec((1, 4), lambda i, s: (i, 0))]
-            + [pl.BlockSpec((1, 4), lambda i, s: (0, 0))] * 3
-            + [pl.BlockSpec((1, 4), lambda i, s: (i, 0))],
+            in_specs=[shared, shared, shared, row_blk, row_blk, shared, shared,
+                      row4]
+            + [pl.BlockSpec((1, 4), lambda i, s: (_I0, _I0))] * 3 + [row4],
             out_specs=[
-                rowed,
-                pl.BlockSpec((1, 1), lambda i, s: (i, 0),
+                row_blk,
+                pl.BlockSpec((1, 1, 1), lambda i, s: (i, _I0, _I0),
                              memory_space=pltpu.SMEM),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((r, dp), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, dp), f32),
+            jax.ShapeDtypeStruct((r, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(
         scal,
-        prep(free_mem), prep(queued), prep(batch), prep(hit_rows),
-        prep(tier_rows, jnp.int32), prep(healthy), prep(iter_scale), rscal,
-        jnp.asarray(tier_bw, jnp.float32).reshape(1, 4),
-        jnp.asarray(tier_lat, jnp.float32).reshape(1, 4),
-        jnp.asarray(congestion, jnp.float32).reshape(1, 4),
-        infl_rows,
+        prep(free_mem), prep(queued), prep(batch), rowed(hit_rows),
+        rowed(tier_rows, jnp.int32), prep(healthy), prep(iter_scale), rscal,
+        jnp.asarray(tier_bw, f32).reshape(1, 4),
+        jnp.asarray(tier_lat, f32).reshape(1, 4),
+        jnp.asarray(congestion, f32).reshape(1, 4),
+        infl_rows.reshape(r, 1, 4),
     )
-    return costs[:rq, :d], best[:rq, 0]
+    return costs[:rq, 0, :d], best[:rq, 0, 0]
 
 
 def _netkv_score_cohort_np(free_mem, queued, batch, hit_rows, tier_rows,
@@ -182,8 +202,6 @@ def _netkv_score_cohort_np(free_mem, queued, batch, hit_rows, tier_rows,
                            infl_rows, *, s_r, input_len, iter_a, iter_b,
                            m_min, beta_max):
     """f32 NumPy twin of the cohort kernel (same op order, no XLA)."""
-    import numpy as np
-
     f32 = np.float32
     d = free_mem.shape[0]
     free = np.asarray(free_mem, f32)[None, :]

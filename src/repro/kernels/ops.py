@@ -2,8 +2,9 @@
 
 On a TPU backend the kernels run compiled (Mosaic); on CPU they execute in
 ``interpret=True`` mode, which runs the kernel body op-by-op and is the
-validation path in this container.  ``force_reference=True`` switches to the
-pure-jnp oracle (used by the serving engine when kernels are disabled).
+validation path of the CPU test suite.  :func:`interpret_mode` is the one
+place that decides between the two.  ``force_reference=True`` switches to
+the pure-jnp oracle (used by the serving engine when kernels are disabled).
 """
 
 from __future__ import annotations
@@ -19,7 +20,10 @@ from .netkv_score import netkv_score as _netkv_score
 from .rwkv_scan import rwkv_scan as _rwkv_scan
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """True when Pallas kernels must run in interpret mode: JAX's default
+    backend is not a TPU (the CPU test suite).  Every kernel call site asks
+    here; a chip run asserts it is False."""
     return jax.default_backend() != "tpu"
 
 
@@ -29,21 +33,21 @@ def flash_decode(q, k_cache, v_cache, pos, *, block_s: int = 512,
     if force_reference:
         return _ref.flash_decode_ref(q, k_cache, v_cache, pos)
     return _flash_decode(q, k_cache, v_cache, pos, block_s=block_s,
-                         interpret=_interpret())
+                         interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("force_reference",))
 def kv_pack(pool, block_table, *, force_reference: bool = False):
     if force_reference:
         return _ref.kv_pack_ref(pool, block_table)
-    return _kv_pack(pool, block_table, interpret=_interpret())
+    return _kv_pack(pool, block_table, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("force_reference",), donate_argnums=(0,))
 def kv_unpack(pool, buf, block_table, *, force_reference: bool = False):
     if force_reference:
         return _ref.kv_unpack_ref(pool, buf, block_table)
-    return _kv_unpack(pool, buf, block_table, interpret=_interpret())
+    return _kv_unpack(pool, buf, block_table, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -61,7 +65,7 @@ def _netkv_score_jit(free_mem, queued, batch, hit_tokens, tier, healthy, iter_sc
     return _netkv_score(
         free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
         tier_bw, tier_lat, congestion, n_inflight,
-        interpret=_interpret(), **kw)
+        interpret=interpret_mode(), **kw)
 
 
 def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
@@ -82,4 +86,4 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
 def rwkv_scan(r, k, v, w, u, *, chunk: int = 128, force_reference: bool = False):
     if force_reference:
         return _ref.rwkv_scan_ref(r, k, v, w, u)
-    return _rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=_interpret())
+    return _rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=interpret_mode())

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax moved TPUCompilerParams -> CompilerParams across releases;
-# resolve whichever this version ships.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, state_scr,
                  *, chunk: int):
@@ -76,7 +72,7 @@ def rwkv_scan(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((b, h, dh, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

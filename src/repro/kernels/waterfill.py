@@ -39,25 +39,35 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.jaxutil import enable_f64
+from .ops import interpret_mode
 
 LANES = 128
 BIG = 3.0e38
+# Block indices typed int32: a bare 0 turns int64 under jax_enable_x64,
+# which Mosaic refuses in an index_map.
+_I0 = np.int32(0)
 
 
 # ------------------------------------------------------------ Pallas kernel
 def _share_argmin_kernel(caps_ref, counts_ref, best_ref, share_ref, *,
                          n_real: int):
-    """shares = caps/counts where counts>0 (else BIG); emit (argmin, min)."""
+    """shares = caps/counts where counts>0 (else BIG); emit (argmin, min).
+
+    Constants and the argmin index are typed f32/int32 so the kernel
+    lowers the same whether or not ``jax_enable_x64`` is on (Mosaic has no
+    f64 and takes int32 reduction indices only)."""
+    f32 = jnp.float32
     caps = caps_ref[...]
     counts = counts_ref[...]
     lane = jax.lax.broadcasted_iota(jnp.int32, caps.shape, 1)
-    ok = (counts > 0.0) & (lane < n_real)
-    shares = jnp.where(ok, caps / jnp.where(ok, counts, 1.0), BIG)
-    best_ref[0, 0] = jnp.argmin(shares[0]).astype(jnp.int32)
+    ok = (counts > f32(0.0)) & (lane < jnp.int32(n_real))
+    shares = jnp.where(ok, caps / jnp.where(ok, counts, f32(1.0)), f32(BIG))
+    best_ref[0, 0] = jax.lax.argmin(shares[0], 0, jnp.int32)
     # min == shares[argmin] bitwise; a reduction avoids a dynamic gather.
     share_ref[0, 0] = jnp.min(shares)
 
@@ -76,10 +86,10 @@ def _pallas_share_argmin(caps_p, counts, interpret: bool):
     best, share = pl.pallas_call(
         kern,
         grid=(1,),
-        in_specs=[pl.BlockSpec((1, dp), lambda i: (0, 0))] * 2,
+        in_specs=[pl.BlockSpec((1, dp), lambda i: (_I0, _I0))] * 2,
         out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1), lambda i: (_I0, _I0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1), lambda i: (_I0, _I0), memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
@@ -181,10 +191,12 @@ def waterfill_fixed_point(paths, caps, active, *, use_pallas: bool = False,
 # ------------------------------------------------- parallel fixed point
 def _shares_kernel(caps_ref, counts_ref, out_ref):
     """Elementwise fair shares: caps/counts where counts>0, BIG elsewhere."""
+    f32 = jnp.float32
     caps = caps_ref[...]
     counts = counts_ref[...]
-    ok = counts > 0.0
-    out_ref[...] = jnp.where(ok, caps / jnp.where(ok, counts, 1.0), BIG)
+    ok = counts > f32(0.0)
+    out_ref[...] = jnp.where(ok, caps / jnp.where(ok, counts, f32(1.0)),
+                             f32(BIG))
 
 
 def _pallas_shares(caps, counts, interpret: bool):
@@ -199,8 +211,8 @@ def _pallas_shares(caps, counts, interpret: bool):
     out = pl.pallas_call(
         _shares_kernel,
         grid=(1,),
-        in_specs=[pl.BlockSpec((1, dp), lambda i: (0, 0))] * 2,
-        out_specs=pl.BlockSpec((1, dp), lambda i: (0, 0)),
+        in_specs=[pl.BlockSpec((1, dp), lambda i: (_I0, _I0))] * 2,
+        out_specs=pl.BlockSpec((1, dp), lambda i: (_I0, _I0)),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         interpret=interpret,
     )(c.reshape(1, dp), k.reshape(1, dp))
@@ -303,8 +315,7 @@ def _waterfill_jit(paths, caps, active, *, use_pallas, interpret):
                                  interpret=interpret)
 
 
-def waterfill_rates(paths, caps, active=None, *, backend: str = "jax",
-                    interpret: bool | None = None):
+def waterfill_rates(paths, caps, active=None, *, backend: str = "jax"):
     """Public entry: jitted water-filling over one flow table.
 
     ``backend="jax"`` is the f64 bit-exact path; ``backend="pallas"`` runs
@@ -313,8 +324,6 @@ def waterfill_rates(paths, caps, active=None, *, backend: str = "jax",
     enable_f64()
     if backend not in ("jax", "pallas"):
         raise ValueError(f"unknown waterfill backend {backend!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     paths = jnp.asarray(paths, jnp.int32)
     caps = jnp.asarray(caps, jnp.float64)
     if active is None:
@@ -323,4 +332,4 @@ def waterfill_rates(paths, caps, active=None, *, backend: str = "jax",
         active = jnp.asarray(active, bool)
     return _waterfill_jit(paths, caps, active,
                           use_pallas=(backend == "pallas"),
-                          interpret=interpret)
+                          interpret=interpret_mode())

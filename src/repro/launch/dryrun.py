@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (device count locks at
-# first backend init).  Everything below may import jax.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import (device count and platform
+# lock at first backend init).  The dry-run is a host-device analysis: it
+# and the children it starts (which inherit this environment) never take an
+# accelerator.  Everything below may import jax.
 
 import argparse
 import json

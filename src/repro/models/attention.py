@@ -4,9 +4,8 @@ bidirectional (encoder) and cross (enc-dec decoder) variants.
 The prefill/train path never materialises the full S x S score matrix: a
 ``lax.scan`` over query chunks keeps live memory at (B, Hq, chunk, S) — the
 pure-XLA analogue of flash attention, required for the 32K-prefill shapes on
-a 16 GB HBM budget.  On real TPU the decode path is replaced by the Pallas
-``flash_decode`` kernel (repro.kernels.ops); the XLA path here is its oracle
-and the dry-run lowering target.
+a 16 GB HBM budget.  Decode attention is this XLA path on every backend; no
+model calls the Pallas ``flash_decode`` kernel (repro.kernels.ops).
 """
 
 from __future__ import annotations
@@ -223,16 +222,6 @@ def seq_sharded_decode_attention(q, k_cache, v_cache, pos, k_new, v_new, *,
     re-gather (EXPERIMENTS.md §Perf decode iteration 3).  The self-token
     term is added on shard 0 only.
     """
-    # jax promoted shard_map to the top level and renamed check_rep ->
-    # check_vma across releases; resolve whichever this version ships
-    # (mirrors the pltpu.CompilerParams shim).
-    try:
-        from jax import shard_map
-        replication_check = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        replication_check = {"check_rep": False}
-
     b, _, h, dh = q.shape
     kv = k_cache.shape[2]
     assert h % kv == 0
@@ -266,12 +255,12 @@ def seq_sharded_decode_attention(q, k_cache, v_cache, pos, k_new, v_new, *,
         return (num / jnp.maximum(den, 1e-30)).astype(qg.dtype)
 
     qg = q.reshape(b, 1, kv, g, dh)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bt, None, None, None, None), P(bt, sq, None, None),
                   P(bt, sq, None, None), P(), P(bt, None, None, None),
                   P(bt, None, None, None)),
         out_specs=P(bt, None, None, None, None),
-        **replication_check,
+        check_vma=False,
     )(qg, k_cache, v_cache, jnp.asarray(pos, jnp.int32), k_new, v_new)
     return out.reshape(b, 1, h, dh)

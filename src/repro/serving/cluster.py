@@ -6,6 +6,12 @@ through kv_pack/kv_unpack; the flow-level fat-tree provides transfer
 *timing*; NetKV (or any ladder policy) picks the decode instance per
 request.  Generated tokens are exact (tests compare against a monolithic
 forward), while TTFT statistics come from the simulated clock.
+
+Engines are placed on the devices the cluster is given (all local devices
+by default): prefill engines on the first, decode engines round-robin on
+the rest, or all on the one device when there is only one.  The packed
+KV buffers are put on the decode engine's device before they are unpacked,
+so on several chips the transfer crosses the chip interconnect.
 """
 
 from __future__ import annotations
@@ -13,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import numpy as np
 
 from repro.cluster.network import BackgroundTraffic, FlowNetwork
@@ -42,6 +49,7 @@ class ServeResult:
     decode_instance: int
     tier: int
     transfer_bytes: int
+    hit_pages: int        # prompt pages the decode engine already held
     ttft: float           # simulated-clock TTFT
     transfer_time: float
 
@@ -52,12 +60,21 @@ class DisaggregatedCluster:
     def __init__(self, cfg: ModelConfig, *, scheduler: str = "netkv-full",
                  n_prefill: int = 2, n_decode: int = 4, n_slots: int = 4,
                  cache_len: int = 256, seed: int = 0,
-                 tree: FatTree | None = None, background: float = 0.2):
-        import jax
-
+                 tree: FatTree | None = None, background: float = 0.2,
+                 devices: Sequence | None = None):
         self.cfg = cfg
         self.cache_len = cache_len
-        params = init_params(cfg, jax.random.PRNGKey(seed))
+        params = init_params(cfg, jax.random.PRNGKey(seed),
+                             dtype=cfg.compute_dtype)
+        devices = list(devices or jax.local_devices())
+        pre_devs, dec_devs = devices[:1], (devices[1:] or devices)
+        placed: dict = {}
+
+        def params_on(dev):
+            if dev not in placed:
+                placed[dev] = jax.device_put(params, dev)
+            return placed[dev]
+
         self.tree = tree or FatTree()
         self.net = FlowNetwork(self.tree, BackgroundTraffic(background), seed=seed)
         pre_meta, dec_meta = make_instances(self.tree, tp=4,
@@ -65,13 +82,16 @@ class DisaggregatedCluster:
         pre_meta = pre_meta[:n_prefill]
         dec_meta = dec_meta[:n_decode]
         self.prefill = [
-            PrefillEngine(m.instance_id, cfg, params, cache_len) for m in pre_meta
+            PrefillEngine(m.instance_id, cfg,
+                          params_on(pre_devs[i % len(pre_devs)]), cache_len)
+            for i, m in enumerate(pre_meta)
         ]
-        self.decode = [
-            DecodeEngine(m.instance_id, cfg, params, n_slots=n_slots,
-                         cache_len=cache_len)
-            for m in dec_meta
-        ]
+        self.decode = []
+        for i, m in enumerate(dec_meta):
+            dev = dec_devs[i % len(dec_devs)]
+            self.decode.append(DecodeEngine(
+                m.instance_id, cfg, params_on(dev), n_slots=n_slots,
+                cache_len=cache_len, device=dev))
         self._server_of = {m.instance_id: m.server for m in (*pre_meta, *dec_meta)}
         self.iter_model = IterTimeModel(a=0.0124, b=1.6e-5)
         self.oracle = NetworkCostOracle(
@@ -83,26 +103,8 @@ class DisaggregatedCluster:
         self.sched = make_scheduler(scheduler, self.iter_model, beta_max=n_slots,
                                     m_min=0.0)
         self.clock = 0.0
-        # Per-decode-instance block-hash sets for the prefix-hit signal.
-        self._cached_hashes: dict[int, set] = {d.instance_id: set() for d in self.decode}
 
     # ------------------------------------------------------------------ serve
-    def _hit_pages(self, decode_id: int, prompt: np.ndarray) -> int:
-        cached = self._cached_hashes[decode_id]
-        pages = 0
-        for start in range(0, len(prompt) - len(prompt) % B_TOK, B_TOK):
-            h = hash(tuple(prompt[start:start + B_TOK].tolist()))
-            if h in cached:
-                pages += 1
-            else:
-                break
-        return pages
-
-    def _remember(self, decode_id: int, prompt: np.ndarray) -> None:
-        cached = self._cached_hashes[decode_id]
-        for start in range(0, len(prompt) - len(prompt) % B_TOK, B_TOK):
-            cached.add(hash(tuple(prompt[start:start + B_TOK].tolist())))
-
     def serve(self, requests: Sequence[ServeRequest]) -> list[ServeResult]:
         results = []
         for req in sorted(requests, key=lambda r: r.arrival):
@@ -122,7 +124,7 @@ class DisaggregatedCluster:
                     free_memory=float(len(d.free_slots())) * 1e12,  # slot-gated
                     queued=0,
                     batch=d.beta,
-                    hit_tokens=float(self._hit_pages(d.instance_id, req.prompt) * B_TOK),
+                    hit_tokens=float(d.hit_pages(req.prompt) * B_TOK),
                     healthy=len(d.free_slots()) > 0,
                 )
             info = RequestInfo(req.request_id, len(req.prompt), float(pre.kv_bytes))
@@ -131,7 +133,7 @@ class DisaggregatedCluster:
             de = next(d for d in self.decode if d.instance_id == decision.instance_id)
 
             # 3. pack + timed transfer + unpack (real tensors move).
-            hit_pages = self._hit_pages(de.instance_id, req.prompt)
+            hit_pages = de.hit_pages(req.prompt)
             buffers, nbytes = pack_transfer(pre.cache, hit_pages)
             done = []
             self.net.start_transfer(
@@ -147,7 +149,11 @@ class DisaggregatedCluster:
                 t = nxt
                 self.net.advance(t)
             t_transfer_done = done[0] if done else t_prefill_done
-            cache = dict(unpack_transfer(buffers, pre.cache))
+            moved = {name: (jax.device_put(buf, de.device), table)
+                     for name, (buf, table) in buffers.items()}
+            cache = de.fill_prefix(
+                unpack_transfer(moved, pre.cache, device=de.device),
+                req.prompt, hit_pages)
             cache["pos"] = pre.cache["pos"]
             pre_landed = dataclasses.replace(pre, cache=cache)
 
@@ -155,7 +161,7 @@ class DisaggregatedCluster:
             de.admit(req.request_id, pre_landed, req.max_new)
             if self.sched.uses_self_contention:
                 self.inflight.decr(pe.instance_id, decision.tier)
-            self._remember(de.instance_id, req.prompt)
+            de.remember(req.prompt, cache)
             toks = [pre.first_token]
             while any(s.active and s.request_id == req.request_id for s in de.slots):
                 emitted = de.step()
@@ -168,6 +174,7 @@ class DisaggregatedCluster:
                 decode_instance=de.instance_id,
                 tier=decision.tier,
                 transfer_bytes=nbytes,
+                hit_pages=hit_pages,
                 ttft=t_first - req.arrival + prefill_time,
                 transfer_time=t_transfer_done - t_prefill_done,
             ))

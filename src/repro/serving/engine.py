@@ -1,11 +1,12 @@
 """Real-JAX serving engines: prefill + slot-based continuous-batching decode.
 
-This is the *executable* serving path (smoke-scale models on CPU, full scale
-on TPU): real tokens through real model weights, with the KV cache moving
-prefill -> decode through the kv_pack/kv_unpack kernels, routed by a NetKV
-scheduler.  The flow-level network simulator provides transfer *timing*;
-the tensors themselves move for real, so generated text is end-to-end
-correct (verified in tests against a monolithic forward).
+This is the *executable* serving path (smoke-scale models on CPU, full
+width on one TPU): real tokens through real model weights, with the KV
+cache moving prefill -> decode through the kv_pack/kv_unpack kernels,
+routed by a NetKV scheduler.  The flow-level network simulator provides
+transfer *timing*; the tensors themselves move for real, so generated text
+is end-to-end correct (verified in tests against a monolithic forward).
+Each engine runs on the device that holds its parameters.
 """
 
 from __future__ import annotations
@@ -44,12 +45,32 @@ class PrefillEngine:
         toks = jnp.asarray(tokens, jnp.int32)[None, :]
         logits, cache = self._fn(self.params, toks)
         nxt = int(jnp.argmax(logits[0, -1]))
+        # Eq. (1): attention KV counts the prompt's tokens, not the padded
+        # cache; fixed-size state counts whole.
+        n = len(tokens)
         kv_bytes = sum(
-            int(np.prod(v.shape)) * v.dtype.itemsize
+            (v.size // v.shape[2] * n if _is_kv(k, v) else v.size)
+            * v.dtype.itemsize
             for k, v in cache.items()
             if k != "pos" and hasattr(v, "shape")
         )
         return PrefillResult(request_id, cache, logits, nxt, kv_bytes)
+
+
+def _is_kv(name: str, leaf) -> bool:
+    """Attention K/V leaf of a decode cache: (P, B, S, KV, dh)."""
+    return name.startswith(("k", "v")) and leaf.ndim == 5
+
+
+def page_hashes(prompt: np.ndarray, page_tokens: int = B_TOK) -> list[int]:
+    """One chained hash per full page of ``prompt``: page i's hash covers
+    pages 0..i, so equal hashes mean equal KV (same tokens, same
+    positions, same preceding context)."""
+    out, h = [], 0
+    for start in range(0, len(prompt) - page_tokens + 1, page_tokens):
+        h = hash((h, tuple(prompt[start:start + page_tokens].tolist())))
+        out.append(h)
+    return out
 
 
 @dataclasses.dataclass
@@ -67,18 +88,23 @@ class DecodeEngine:
     TPU serving engines)."""
 
     def __init__(self, instance_id: int, cfg: ModelConfig, params, *,
-                 n_slots: int, cache_len: int):
+                 n_slots: int, cache_len: int, device=None):
         self.instance_id = instance_id
         self.cfg = cfg
         self.params = params
+        self.device = device
         self.n_slots = n_slots
         self.cache_len = cache_len
         self.slots = [Slot() for _ in range(n_slots)]
         abstract = make_decode_cache(cfg, n_slots, cache_len)
         self.cache = {
-            k: (jnp.zeros(v.shape, v.dtype) if k != "pos" else jnp.int32(0))
+            k: (jnp.zeros(v.shape, v.dtype, device=device) if k != "pos"
+                else jnp.int32(0))
             for k, v in abstract.items()
         }
+        # Prefix cache: chained page hash -> the K/V of the prompt that
+        # first brought the page here, cut to its full pages.  Unbounded.
+        self._prefix: dict[int, dict] = {}
         self._pos = np.zeros(n_slots, np.int32)          # per-slot position
         self._tokens = np.zeros(n_slots, np.int32)       # next input token
         self._step_fn = jax.jit(self._make_step())
@@ -105,6 +131,36 @@ class DecodeEngine:
     def beta(self) -> int:
         return sum(1 for s in self.slots if s.active)
 
+    # -- prefix cache ------------------------------------------------------
+    def hit_pages(self, prompt: np.ndarray) -> int:
+        """Leading pages of ``prompt`` whose KV this engine already holds."""
+        n = 0
+        for h in page_hashes(prompt):
+            if h not in self._prefix:
+                break
+            n += 1
+        return n
+
+    def fill_prefix(self, cache: dict, prompt: np.ndarray, hit_pages: int) -> dict:
+        """Write the first ``hit_pages`` pages of K/V, which the transfer
+        skipped, into a landed per-request cache from the prefix cache."""
+        if hit_pages == 0:
+            return cache
+        src = self._prefix[page_hashes(prompt)[hit_pages - 1]]
+        n = hit_pages * B_TOK
+        return {k: (v.at[:, :, :n].set(src[k][:, :, :n]) if k in src else v)
+                for k, v in cache.items()}
+
+    def remember(self, prompt: np.ndarray, cache: dict) -> None:
+        """Keep the full pages of a landed request's K/V as prefix pages."""
+        hashes = page_hashes(prompt)
+        if not hashes or hashes[-1] in self._prefix:
+            return
+        n = len(hashes) * B_TOK
+        kv = {k: v[:, :, :n] for k, v in cache.items() if _is_kv(k, v)}
+        for h in hashes:
+            self._prefix.setdefault(h, kv)
+
     def admit(self, request_id: int, pre: PrefillResult, max_new: int) -> int:
         """Land a transferred prefill cache into a free slot."""
         free = self.free_slots()
@@ -118,7 +174,7 @@ class DecodeEngine:
                 continue
             src = pre.cache[k]
             if src.ndim >= 2 and src.shape[1] == 1:       # (P, 1, ...) batch lane
-                if k.startswith(("k", "v")) and src.ndim == 5:
+                if _is_kv(k, src):
                     src_fit = src[:, 0, : self.cache_len]
                     v = v.at[:, slot, : src_fit.shape[1]].set(src_fit)
                 else:

@@ -1,7 +1,9 @@
 """KV transfer path: page the prefill cache, pack to a contiguous buffer.
 
-On TPU the pack runs the Pallas ``kv_pack`` kernel (single large DMA out);
-here it validates in interpret mode.  The byte count it returns is what the
+The pack runs the Pallas ``kv_pack`` kernel (one contiguous buffer per
+leaf, so the transfer is a single large copy) and the decode side lands it
+with ``kv_unpack``: compiled by Mosaic on a TPU, in interpret mode on the
+CPU (``kernels.ops.interpret_mode``).  The byte count it returns is what the
 NetKV cost model prices (Eq. 1/2): callers skip packing the prefix-hit pages
 (Eq. 2's lambda term).
 """
@@ -100,8 +102,11 @@ def merge_chunk_buffers(chunks: list[dict]) -> dict:
     return out
 
 
-def unpack_transfer(buffers: dict, like_cache: dict, page_tokens: int = B_TOK):
-    """Reassemble a per-request cache dict from transfer buffers."""
+def unpack_transfer(buffers: dict, like_cache: dict, page_tokens: int = B_TOK,
+                    *, device=None):
+    """Reassemble a per-request cache dict from transfer buffers on
+    ``device`` (JAX's default device when None), where the caller has
+    already put the buffers."""
     out = {}
     for name, leaf in like_cache.items():
         if name == "pos" or not hasattr(leaf, "shape"):
@@ -114,10 +119,10 @@ def unpack_transfer(buffers: dict, like_cache: dict, page_tokens: int = B_TOK):
                 pool = jnp.zeros(
                     (int(np.prod((leaf.shape[0], leaf.shape[2] // page_tokens))),
                      page_tokens, leaf.shape[3], leaf.shape[4]),
-                    leaf.dtype,
+                    leaf.dtype, device=device,
                 )
                 pool = ops.kv_unpack(pool, buf, jnp.asarray(table, jnp.int32))
                 out[name] = pool.reshape(leaf.shape)
         else:
-            out[name] = jnp.zeros(leaf.shape, leaf.dtype)
+            out[name] = jnp.zeros(leaf.shape, leaf.dtype, device=device)
     return out

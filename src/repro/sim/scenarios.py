@@ -189,10 +189,7 @@ class ScenarioPlane:
     """
 
     def __init__(self, scenarios: Sequence[ScenarioSpec], *, dt: float = 0.01,
-                 backend: str = "jax", max_requests: int | None = None,
-                 interpret: bool | None = None):
-        import jax
-
+                 backend: str = "jax", max_requests: int | None = None):
         enable_f64()
         if not scenarios:
             raise ValueError("need at least one scenario")
@@ -209,8 +206,6 @@ class ScenarioPlane:
         self.scenarios = list(scenarios)
         self.dt = float(dt)
         self.backend = backend
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else bool(interpret))
         self.n_steps = int(math.ceil(scenarios[0].horizon / self.dt))
         self._prep(max_requests)
 
@@ -377,10 +372,19 @@ class ScenarioPlane:
         ``detail=True`` adds per-request ``t_first``/``t_fin``/``tokens``
         (the vmap-consistency test surface).
         """
+        out = self._sweep_jit()(*self._sweep_args())
+        res = {k: np.asarray(v) for k, v in out.items()}
+        if not detail:
+            for k in ("t_first", "t_fin", "tokens"):
+                res.pop(k)
+        return res
+
+    def _sweep_args(self) -> tuple:
+        """The sweep program's arguments, scenario axis first."""
         import jax
         import jax.numpy as jnp
 
-        out = self._sweep_jit()(
+        return (
             jnp.asarray(self.arrival), jnp.asarray(self.s_eff),
             jnp.asarray(self.out_len), jnp.asarray(self.slo),
             jnp.asarray(self.src_p), jnp.asarray(self.prefill_end),
@@ -395,20 +399,17 @@ class ScenarioPlane:
             jnp.asarray(self.warmup_arr), jnp.asarray(self.measure_arr),
             jax.vmap(jax.random.PRNGKey)(jnp.asarray(self.seeds)),
         )
-        res = {k: np.asarray(v) for k, v in out.items()}
-        if not detail:
-            for k in ("t_first", "t_fin", "tokens"):
-                res.pop(k)
-        return res
 
     def _sweep_jit(self):
         import jax
+
+        from repro.kernels.ops import interpret_mode
 
         if not hasattr(self, "_jitted"):
             one = lambda *a: _run_one(
                 *a, tier_pd=self.tier_pd, dt=self.dt, n_steps=self.n_steps,
                 use_pallas=(self.backend == "pallas"),
-                interpret=self.interpret,
+                interpret=interpret_mode(),
                 link_tier=self._link_tier_c)
             self._jitted = jax.jit(jax.vmap(one))
         return self._jitted

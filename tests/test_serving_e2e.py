@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_spec
+from repro.core.cost import B_TOK
 from repro.models import decode_step, forward_logits, init_params, prefill
 from repro.serving import (
     DisaggregatedCluster,
@@ -90,6 +91,17 @@ class TestTransferPath:
         np.testing.assert_allclose(np.asarray(lg1), np.asarray(lg2), atol=1e-6)
 
 
+def _assert_monolithic(cfg, reqs, res):
+    """Served tokens equal greedy decoding by a monolithic forward."""
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    for r, req in zip(res, reqs):
+        toks = list(req.prompt)
+        for _ in range(req.max_new):
+            lg, _ = forward_logits(cfg, params, jnp.asarray(toks, jnp.int32)[None])
+            toks.append(int(jnp.argmax(lg[0, -1])))
+        assert r.tokens[:req.max_new] == toks[len(req.prompt):], r.request_id
+
+
 class TestEndToEndServing:
     def test_token_exact_vs_monolithic(self, smoke_cfg):
         cfg = smoke_cfg
@@ -97,14 +109,23 @@ class TestEndToEndServing:
         rng = np.random.default_rng(0)
         reqs = [ServeRequest(i, rng.integers(0, cfg.vocab_size, size=20),
                              max_new=6, arrival=i * 0.01) for i in range(4)]
+        _assert_monolithic(cfg, reqs, cluster.serve(reqs))
+
+    def test_prefix_hit_tokens_exact(self, smoke_cfg):
+        """The pages a prefix hit leaves out of the transfer come from the
+        decode engine's own prefix cache: tokens stay exact after a hit."""
+        cfg = smoke_cfg
+        cluster = DisaggregatedCluster(cfg, scheduler="rr", cache_len=64,
+                                       n_decode=1)
+        rng = np.random.default_rng(3)
+        prefix = rng.integers(0, cfg.vocab_size, size=2 * B_TOK)
+        reqs = [ServeRequest(i, np.concatenate(
+                    [prefix, rng.integers(0, cfg.vocab_size, size=8)]),
+                    max_new=4, arrival=i * 0.5) for i in range(2)]
         res = cluster.serve(reqs)
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        for r, req in zip(res, reqs):
-            toks = list(req.prompt)
-            for _ in range(req.max_new):
-                lg, _ = forward_logits(cfg, params, jnp.asarray(toks, jnp.int32)[None])
-                toks.append(int(jnp.argmax(lg[0, -1])))
-            assert r.tokens[:req.max_new] == toks[len(req.prompt):], r.request_id
+        assert [r.hit_pages for r in res] == [0, 2]
+        assert res[1].transfer_bytes < res[0].transfer_bytes
+        _assert_monolithic(cfg, reqs, res)
 
     def test_prefix_sharing_cuts_transfer(self, smoke_cfg):
         cfg = smoke_cfg
@@ -132,6 +153,18 @@ class TestEndToEndServing:
                                  max_new=3) for i in range(3)]
             res = cluster.serve(reqs)
             assert all(len(r.tokens) >= 3 for r in res), sched
+
+    def test_serve_launcher_smoke(self, capsys, monkeypatch):
+        from repro.core import jaxutil
+        from repro.launch.serve import main
+
+        # The launcher turns on the persistent compile cache; keep this
+        # test process out of it.
+        monkeypatch.setattr(jaxutil, "use_compile_cache", lambda: "")
+        assert main(["--real", "--smoke", "--arch", "qwen3-14b",
+                     "--requests", "2"]) == 0
+        lines = capsys.readouterr().out.splitlines()
+        assert [ln.split(":")[0] for ln in lines] == ["req0", "req1"]
 
 
 class TestCheckpointRestart:
