@@ -62,8 +62,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None, help="comma-separated harness names")
     ap.add_argument("--profile", action="store_true",
-                    help="write per-lane/per-handler event dispatch times "
-                         "to artifacts/bench/event_profile.csv")
+                    help="write per-lane/per-handler event dispatch self "
+                         "times and per-span rows to "
+                         "artifacts/bench/event_profile.csv")
     ap.add_argument("--trace", action="store_true",
                     help="record TracePlane spans + decision forensics in "
                          "every simulation the selected harnesses run; "
@@ -74,7 +75,7 @@ def main() -> None:
 
     use_compile_cache()
     if args.profile:
-        from repro.sim.engine import enable_profiling
+        from repro.profiling import enable_profiling
         enable_profiling(True)
     if args.trace:
         from repro.sim import enable_tracing
@@ -92,7 +93,7 @@ def main() -> None:
             print(f"{name},{(time.time()-t0)*1e6:.0f},ERROR:{type(e).__name__}:{e}")
             traceback.print_exc(file=sys.stderr)
     if args.profile:
-        from repro.sim.engine import profile_rows
+        from repro.profiling import profile_rows
 
         from .common import write_csv
         rows = profile_rows()

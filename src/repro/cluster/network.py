@@ -42,6 +42,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..profiling import span
 from .topology import FatTree, MAX_PATH_LEN, NicPolicy, make_nic_policy
 
 
@@ -446,78 +447,79 @@ class FlowPlane:
         over link-disjoint components, so untouched flows keep their rates
         (bit-for-bit what a full recompute would assign them).
         """
-        if not self._slot_order:
-            return
-        slots = self._ordered_slots()
-        P = self.f_path[slots]                       # (k, MAX_PATH_LEN)
-        pad = self._pad
-        if dirty_links is not None:
-            link_dirty = np.zeros(pad + 1, bool)
-            link_dirty[dirty_links] = True
-            link_dirty[pad] = False
-            flow_dirty = np.zeros(len(slots), bool)
-            while True:
-                hit = link_dirty[P].any(axis=1) & ~flow_dirty
-                if not hit.any():
-                    break
-                flow_dirty |= hit
-                link_dirty[self.f_path[slots[hit]].ravel()] = True
-                link_dirty[pad] = False
-            if not flow_dirty.any():
+        with span("waterfill"):
+            if not self._slot_order:
                 return
-            slots = slots[flow_dirty]
-            P = P[flow_dirty]
-        k = len(slots)
-        flat = P.ravel()                             # row-major: flow x hop
-        # First-encounter order per link (flow-creation x hop order) — the
-        # tie-break the reference's insertion-ordered dict scan applies.
-        # The whole fixed point runs in *encounter-permuted* link space so
-        # the per-round bottleneck pick is a single argmin (first minimum in
-        # scan order == first-encountered link with the minimal share).
-        enc = np.full(pad + 1, flat.size + 1, np.int64)
-        np.minimum.at(enc, flat, np.arange(flat.size))
-        perm = np.argsort(enc, kind="stable")        # unseen links sort last
-        inv = np.empty_like(perm)
-        inv[perm] = np.arange(pad + 1)
-        P = inv[P].astype(self._path_dtype)          # permuted path matrix
-        flat = P.ravel()
-        counts = np.bincount(flat, minlength=pad + 1)
-        ppad = int(inv[pad])
-        counts[ppad] = 0
-        # CSR link -> flow-row index, built once per recompute.  The stable
-        # sort keeps rows in flow-creation order within each link, which is
-        # both the reference's per-link flow order (for the residual
-        # subtraction sequence) and what makes each round O(flows-on-link).
-        csr_order = np.argsort(flat, kind="stable")
-        csr_rows = csr_order // MAX_PATH_LEN
-        csr_start = np.searchsorted(flat[csr_order], np.arange(pad + 2))
-        caps = self._resid_caps[perm]
-        shares = np.empty(pad + 1, np.float64)
-        unfixed = np.ones(k, bool)
-        rates = np.zeros(k, np.float64)
-        n_unfixed = k
-        while n_unfixed:
-            shares.fill(np.inf)
-            np.divide(caps, counts, out=shares, where=counts > 0)
-            lid = int(np.argmin(shares))             # enc-order tie-break
-            share = shares[lid]
-            if share == np.inf:  # pragma: no cover - every flow has links
-                rates[unfixed] = np.inf
-                break
-            if self._wf_trace is not None:
-                self._wf_trace.append((int(perm[lid]), float(share)))
-            rows = csr_rows[csr_start[lid]:csr_start[lid + 1]]
-            fixed_rows = rows[unfixed[rows]]         # flow-creation order
-            rates[fixed_rows] = share
-            if self.record_bottlenecks:
-                self.f_bneck[slots[fixed_rows]] = perm[lid]
-            idx = P[fixed_rows].ravel()              # reference subtraction order
-            np.subtract.at(caps, idx, share)
-            np.maximum(caps, 0.0, out=caps)
-            np.subtract.at(counts, idx, 1)           # padded hops go negative:
-            n_unfixed -= len(fixed_rows)             # counts<=0 is never active
-            unfixed[fixed_rows] = False
-        self.f_rate[slots] = rates
+            slots = self._ordered_slots()
+            P = self.f_path[slots]                       # (k, MAX_PATH_LEN)
+            pad = self._pad
+            if dirty_links is not None:
+                link_dirty = np.zeros(pad + 1, bool)
+                link_dirty[dirty_links] = True
+                link_dirty[pad] = False
+                flow_dirty = np.zeros(len(slots), bool)
+                while True:
+                    hit = link_dirty[P].any(axis=1) & ~flow_dirty
+                    if not hit.any():
+                        break
+                    flow_dirty |= hit
+                    link_dirty[self.f_path[slots[hit]].ravel()] = True
+                    link_dirty[pad] = False
+                if not flow_dirty.any():
+                    return
+                slots = slots[flow_dirty]
+                P = P[flow_dirty]
+            k = len(slots)
+            flat = P.ravel()                             # row-major: flow x hop
+            # First-encounter order per link (flow-creation x hop order) — the
+            # tie-break the reference's insertion-ordered dict scan applies.
+            # The whole fixed point runs in *encounter-permuted* link space so
+            # the per-round bottleneck pick is a single argmin (first minimum in
+            # scan order == first-encountered link with the minimal share).
+            enc = np.full(pad + 1, flat.size + 1, np.int64)
+            np.minimum.at(enc, flat, np.arange(flat.size))
+            perm = np.argsort(enc, kind="stable")        # unseen links sort last
+            inv = np.empty_like(perm)
+            inv[perm] = np.arange(pad + 1)
+            P = inv[P].astype(self._path_dtype)          # permuted path matrix
+            flat = P.ravel()
+            counts = np.bincount(flat, minlength=pad + 1)
+            ppad = int(inv[pad])
+            counts[ppad] = 0
+            # CSR link -> flow-row index, built once per recompute.  The stable
+            # sort keeps rows in flow-creation order within each link, which is
+            # both the reference's per-link flow order (for the residual
+            # subtraction sequence) and what makes each round O(flows-on-link).
+            csr_order = np.argsort(flat, kind="stable")
+            csr_rows = csr_order // MAX_PATH_LEN
+            csr_start = np.searchsorted(flat[csr_order], np.arange(pad + 2))
+            caps = self._resid_caps[perm]
+            shares = np.empty(pad + 1, np.float64)
+            unfixed = np.ones(k, bool)
+            rates = np.zeros(k, np.float64)
+            n_unfixed = k
+            while n_unfixed:
+                shares.fill(np.inf)
+                np.divide(caps, counts, out=shares, where=counts > 0)
+                lid = int(np.argmin(shares))             # enc-order tie-break
+                share = shares[lid]
+                if share == np.inf:  # pragma: no cover - every flow has links
+                    rates[unfixed] = np.inf
+                    break
+                if self._wf_trace is not None:
+                    self._wf_trace.append((int(perm[lid]), float(share)))
+                rows = csr_rows[csr_start[lid]:csr_start[lid + 1]]
+                fixed_rows = rows[unfixed[rows]]         # flow-creation order
+                rates[fixed_rows] = share
+                if self.record_bottlenecks:
+                    self.f_bneck[slots[fixed_rows]] = perm[lid]
+                idx = P[fixed_rows].ravel()              # reference subtraction order
+                np.subtract.at(caps, idx, share)
+                np.maximum(caps, 0.0, out=caps)
+                np.subtract.at(counts, idx, 1)           # padded hops go negative:
+                n_unfixed -= len(fixed_rows)             # counts<=0 is never active
+                unfixed[fixed_rows] = False
+            self.f_rate[slots] = rates
 
     # ------------------------------------------------------------ telemetry
     def open_flow_counts(self) -> np.ndarray:

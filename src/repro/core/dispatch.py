@@ -42,11 +42,11 @@ assignment flipped any candidate's f32 feasibility bit for that row.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from ..profiling import span
 from .cost import deflected_cost, effective_bandwidth_tiers, transfer_time
 from .oracle import OracleView, SelfContentionTracker, TIERS
 from .schedulers import (
@@ -157,67 +157,66 @@ class CohortSelector:
         hit_fn: Optional[Callable[[int, int], float]] = None,
         evictions_fn: Optional[Callable[[int], int]] = None,
     ) -> None:
-        t0 = time.perf_counter()
-        kind = _KIND.get(type(sched))
-        if kind is None:
-            raise ValueError(
-                f"no cohort path for scheduler type {type(sched).__name__}")
-        self._sched = sched
-        self._items = list(items)
-        self._cv = cv
-        self._oracle = oracle
-        self._inflight = inflight
-        self._hit_fn = hit_fn
-        self._evictions_fn = evictions_fn
-        self._kind = kind
-        R = len(self._items)
-        n = cv.n
-        self.H = np.asarray(hit_matrix, np.float64)
-        if self.H.shape != (R, n):
-            raise ValueError(f"hit_matrix shape {self.H.shape} != {(R, n)}")
+        with span("select.cohort_build") as sp:
+            kind = _KIND.get(type(sched))
+            if kind is None:
+                raise ValueError(
+                    f"no cohort path for scheduler type {type(sched).__name__}")
+            self._sched = sched
+            self._items = list(items)
+            self._cv = cv
+            self._oracle = oracle
+            self._inflight = inflight
+            self._hit_fn = hit_fn
+            self._evictions_fn = evictions_fn
+            self._kind = kind
+            R = len(self._items)
+            n = cv.n
+            self.H = np.asarray(hit_matrix, np.float64)
+            if self.H.shape != (R, n):
+                raise ValueError(f"hit_matrix shape {self.H.shape} != {(R, n)}")
 
-        # s_eff as one broadcast: per-element identical to v_s_eff per row
-        # (rows with input_len <= 0 are all-zero there, zeroed here).
-        kv_col = np.array([it.req.kv_bytes for it in self._items],
-                          np.float64)[:, None]
-        l_vec = np.array([it.req.input_len for it in self._items], np.float64)
-        l_col = np.where(l_vec > 0.0, l_vec, 1.0)[:, None]
-        frac = np.minimum(np.maximum(self.H, 0.0), l_col) / l_col
-        self.SE = kv_col * (1.0 - frac)
-        self.SE[l_vec <= 0.0] = 0.0
+            # s_eff as one broadcast: per-element identical to v_s_eff per row
+            # (rows with input_len <= 0 are all-zero there, zeroed here).
+            kv_col = np.array([it.req.kv_bytes for it in self._items],
+                              np.float64)[:, None]
+            l_vec = np.array([it.req.input_len for it in self._items], np.float64)
+            l_col = np.where(l_vec > 0.0, l_vec, 1.0)[:, None]
+            frac = np.minimum(np.maximum(self.H, 0.0), l_col) / l_col
+            self.SE = kv_col * (1.0 - frac)
+            self.SE[l_vec <= 0.0] = 0.0
 
-        self._dirty = np.zeros(R, bool)
-        self._infl_dirty: set[int] = set()
-        self._watch: dict[int, tuple[int, int]] = {}   # iid -> (slot, count)
-        self._load = self._loadn = None
-        self._tx = None
-        self._has_tx = np.zeros(R, bool)
-        self._pl_costs = self._pl_best = self._pl_thr32 = None
-        self._free0 = self._healthy0 = None
+            self._dirty = np.zeros(R, bool)
+            self._infl_dirty: set[int] = set()
+            self._watch: dict[int, tuple[int, int]] = {}   # iid -> (slot, count)
+            self._load = self._loadn = None
+            self._tx = None
+            self._has_tx = np.zeros(R, bool)
+            self._pl_costs = self._pl_best = self._pl_thr32 = None
+            self._free0 = self._healthy0 = None
 
-        if kind in ("la", "ca", "cla"):
-            # Cohort-invariant Eq. (6)/(7): queue/batch/straggler columns do
-            # not move between the rows of one cohort, so the sequential
-            # per-select recompute yields these exact bits every time.
-            load = sched._t_queue_vec(cv) + sched._t_decode_vec(cv)
-            self._load = load
-            if kind == "cla":
-                self._loadn = load / sched.iter_model(sched.beta_max)
-        elif kind == "netkv":
-            self._is_pred = isinstance(sched, NetKVPredictive)
-            self._pallas = sched.backend == "pallas"
-            self._streamed = np.array(
-                [it.req.prefill_remaining > 0.0 or it.req.tail_bytes is not None
-                 for it in self._items], bool)
-            self._t_q = sched._t_queue_vec(cv)
-            self._t_d = sched._t_decode_vec(cv)
-            if not self._is_pred:
-                # NetKVPredictive's congestion read advances its EWMA — a
-                # per-select side effect that must happen at each row's
-                # *turn*, so pred rows always recompute (no precompute).
-                self._build_netkv(R, n)
-        t1 = time.perf_counter()
-        self._setup_s = t1 - t0
+            if kind in ("la", "ca", "cla"):
+                # Cohort-invariant Eq. (6)/(7): queue/batch/straggler columns do
+                # not move between the rows of one cohort, so the sequential
+                # per-select recompute yields these exact bits every time.
+                load = sched._t_queue_vec(cv) + sched._t_decode_vec(cv)
+                self._load = load
+                if kind == "cla":
+                    self._loadn = load / sched.iter_model(sched.beta_max)
+            elif kind == "netkv":
+                self._is_pred = isinstance(sched, NetKVPredictive)
+                self._pallas = sched.backend == "pallas"
+                self._streamed = np.array(
+                    [it.req.prefill_remaining > 0.0 or it.req.tail_bytes is not None
+                     for it in self._items], bool)
+                self._t_q = sched._t_queue_vec(cv)
+                self._t_d = sched._t_decode_vec(cv)
+                if not self._is_pred:
+                    # NetKVPredictive's congestion read advances its EWMA — a
+                    # per-select side effect that must happen at each row's
+                    # *turn*, so pred rows always recompute (no precompute).
+                    self._build_netkv(R, n)
+        self._setup_s = sp.duration
 
     # ------------------------------------------------------------ netkv build
     def _build_netkv(self, R: int, n: int) -> None:
@@ -305,8 +304,9 @@ class CohortSelector:
             interpret=interpret_mode(),
         )
         self._pl_rows = {int(k): i for i, k in enumerate(rows)}
-        self._pl_costs = np.asarray(costs)
-        self._pl_best = np.asarray(best)
+        with span("score.readback"):
+            self._pl_costs = np.asarray(costs)
+            self._pl_best = np.asarray(best)
         self._free0 = cv.column("free_memory").copy()
         self._healthy0 = (cv.column("healthy")
                           & (cv.column("role") == ROLE_DECODE)).copy()

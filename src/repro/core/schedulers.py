@@ -37,6 +37,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..profiling import span
 from .cost import (
     IterTimeModel,
     deflected_cost,
@@ -529,8 +530,9 @@ class NetKVFull(Scheduler):
             m_min=self.m_min, beta_max=self.beta_max,
             interpret=interpret_mode(),
         )
-        j = int(best)
-        best_cost = float(costs[j])
+        with span("score.readback"):
+            j = int(best)
+            best_cost = float(costs[j])
         if not best_cost < BIG / 2:  # all candidates masked infeasible
             return None
         tier = int(tier_row[j])

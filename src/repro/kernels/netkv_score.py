@@ -20,6 +20,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.profiling import span
+
 LANES = 128
 BIG = 3.0e38
 # Block indices typed int32: a bare 0 turns int64 under jax_enable_x64,
@@ -38,12 +40,13 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
     their costs bit-identical (two differently-shaped XLA programs are free
     to fuse/FMA differently; one shared program is not).
     """
+    with span("score.prepare"):
+        hit_rows = jnp.asarray(hit_tokens, jnp.float32).reshape(1, -1)
+        tier_rows = jnp.asarray(tier, jnp.int32).reshape(1, -1)
+        infl_rows = jnp.asarray(n_inflight, jnp.float32).reshape(1, 4)
     costs, best = netkv_score_cohort(
-        free_mem, queued, batch,
-        jnp.asarray(hit_tokens, jnp.float32).reshape(1, -1),
-        jnp.asarray(tier, jnp.int32).reshape(1, -1),
-        healthy, iter_scale, tier_bw, tier_lat, congestion,
-        jnp.asarray(n_inflight, jnp.float32).reshape(1, 4),
+        free_mem, queued, batch, hit_rows, tier_rows,
+        healthy, iter_scale, tier_bw, tier_lat, congestion, infl_rows,
         s_r=[s_r], input_len=[input_len], iter_a=iter_a, iter_b=iter_b,
         m_min=m_min, beta_max=beta_max, interpret=interpret,
     )
@@ -126,75 +129,78 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
             tier_bw, tier_lat, congestion, infl_rows, s_r=s_r,
             input_len=input_len, iter_a=iter_a, iter_b=iter_b, m_min=m_min,
             beta_max=beta_max)
-    r, d = hit_rows.shape[0], free_mem.shape[0]
-    dp = -(-d // LANES) * LANES
-    pad = dp - d
+    with span("score.prepare"):
+        r, d = hit_rows.shape[0], free_mem.shape[0]
+        dp = -(-d // LANES) * LANES
+        pad = dp - d
 
-    hit_rows = jnp.asarray(hit_rows, jnp.float32)
-    tier_rows = jnp.asarray(tier_rows, jnp.int32)
-    infl_rows = jnp.asarray(infl_rows, jnp.float32).reshape(r, 4)
-    s_rv = jnp.asarray(s_r, jnp.float32).reshape(r)
-    l_rv = jnp.asarray(input_len, jnp.float32).reshape(r)
-    rq = r
-    if r == 1:
-        # grid=(1,) unrolls the body and XLA fuses the unrolled program
-        # differently than the r>=2 grid loop (ulp-level cost drift).  Pad
-        # to two identical rows so every call — any cohort size, and the
-        # single-row ``netkv_score`` wrapper — runs the same loop program.
-        hit_rows = jnp.concatenate([hit_rows, hit_rows])
-        tier_rows = jnp.concatenate([tier_rows, tier_rows])
-        infl_rows = jnp.concatenate([infl_rows, infl_rows])
-        s_rv = jnp.concatenate([s_rv, s_rv])
-        l_rv = jnp.concatenate([l_rv, l_rv])
-        r = 2
+        hit_rows = jnp.asarray(hit_rows, jnp.float32)
+        tier_rows = jnp.asarray(tier_rows, jnp.int32)
+        infl_rows = jnp.asarray(infl_rows, jnp.float32).reshape(r, 4)
+        s_rv = jnp.asarray(s_r, jnp.float32).reshape(r)
+        l_rv = jnp.asarray(input_len, jnp.float32).reshape(r)
+        rq = r
+        if r == 1:
+            # grid=(1,) unrolls the body and XLA fuses the unrolled program
+            # differently than the r>=2 grid loop (ulp-level cost drift).  Pad
+            # to two identical rows so every call — any cohort size, and the
+            # single-row ``netkv_score`` wrapper — runs the same loop program.
+            hit_rows = jnp.concatenate([hit_rows, hit_rows])
+            tier_rows = jnp.concatenate([tier_rows, tier_rows])
+            infl_rows = jnp.concatenate([infl_rows, infl_rows])
+            s_rv = jnp.concatenate([s_rv, s_rv])
+            l_rv = jnp.concatenate([l_rv, l_rv])
+            r = 2
 
-    def prep(x, dtype=jnp.float32):
-        x = jnp.asarray(x, dtype)
-        if pad:
-            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-        return x.reshape(-1, dp)
+        def prep(x, dtype=jnp.float32):
+            x = jnp.asarray(x, dtype)
+            if pad:
+                x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+            return x.reshape(-1, dp)
 
-    def rowed(x, dtype=jnp.float32):
-        return prep(x, dtype).reshape(r, 1, dp)
+        def rowed(x, dtype=jnp.float32):
+            return prep(x, dtype).reshape(r, 1, dp)
 
-    f32 = jnp.float32
-    scal = jnp.stack([jnp.asarray(v, f32) for v in
-                      (iter_a, iter_b, m_min, float(beta_max))])
-    rscal = jnp.stack([s_rv, l_rv, jnp.zeros(r, f32), jnp.zeros(r, f32)],
-                      axis=1).reshape(r, 1, 4)
-    kernel = functools.partial(_score_cohort_kernel, n_real=d)
-    shared = pl.BlockSpec((1, dp), lambda i, s: (_I0, _I0))
-    row_blk = pl.BlockSpec((1, 1, dp), lambda i, s: (i, _I0, _I0))
-    row4 = pl.BlockSpec((1, 1, 4), lambda i, s: (i, _I0, _I0))
-    costs, best = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(r,),
-            in_specs=[shared, shared, shared, row_blk, row_blk, shared, shared,
-                      row4]
-            + [pl.BlockSpec((1, 4), lambda i, s: (_I0, _I0))] * 3 + [row4],
-            out_specs=[
-                row_blk,
-                pl.BlockSpec((1, 1, 1), lambda i, s: (i, _I0, _I0),
-                             memory_space=pltpu.SMEM),
+        f32 = jnp.float32
+        scal = jnp.stack([jnp.asarray(v, f32) for v in
+                          (iter_a, iter_b, m_min, float(beta_max))])
+        rscal = jnp.stack([s_rv, l_rv, jnp.zeros(r, f32), jnp.zeros(r, f32)],
+                          axis=1).reshape(r, 1, 4)
+        args = (
+            scal,
+            prep(free_mem), prep(queued), prep(batch), rowed(hit_rows),
+            rowed(tier_rows, jnp.int32), prep(healthy), prep(iter_scale), rscal,
+            jnp.asarray(tier_bw, f32).reshape(1, 4),
+            jnp.asarray(tier_lat, f32).reshape(1, 4),
+            jnp.asarray(congestion, f32).reshape(1, 4),
+            infl_rows.reshape(r, 1, 4),
+        )
+    with span("score.call"):
+        kernel = functools.partial(_score_cohort_kernel, n_real=d)
+        shared = pl.BlockSpec((1, dp), lambda i, s: (_I0, _I0))
+        row_blk = pl.BlockSpec((1, 1, dp), lambda i, s: (i, _I0, _I0))
+        row4 = pl.BlockSpec((1, 1, 4), lambda i, s: (i, _I0, _I0))
+        costs, best = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(r,),
+                in_specs=[shared, shared, shared, row_blk, row_blk, shared, shared,
+                          row4]
+                + [pl.BlockSpec((1, 4), lambda i, s: (_I0, _I0))] * 3 + [row4],
+                out_specs=[
+                    row_blk,
+                    pl.BlockSpec((1, 1, 1), lambda i, s: (i, _I0, _I0),
+                                 memory_space=pltpu.SMEM),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((r, 1, dp), f32),
+                jax.ShapeDtypeStruct((r, 1, 1), jnp.int32),
             ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((r, 1, dp), f32),
-            jax.ShapeDtypeStruct((r, 1, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(
-        scal,
-        prep(free_mem), prep(queued), prep(batch), rowed(hit_rows),
-        rowed(tier_rows, jnp.int32), prep(healthy), prep(iter_scale), rscal,
-        jnp.asarray(tier_bw, f32).reshape(1, 4),
-        jnp.asarray(tier_lat, f32).reshape(1, 4),
-        jnp.asarray(congestion, f32).reshape(1, 4),
-        infl_rows.reshape(r, 1, 4),
-    )
-    return costs[:rq, 0, :d], best[:rq, 0, 0]
+            interpret=interpret,
+        )(*args)
+        return costs[:rq, 0, :d], best[:rq, 0, 0]
 
 
 def _netkv_score_cohort_np(free_mem, queued, batch, hit_rows, tier_rows,

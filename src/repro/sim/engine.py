@@ -40,8 +40,9 @@ from __future__ import annotations
 
 import heapq
 import itertools
-import time as _time
 from typing import Callable, Sequence
+
+from repro.profiling import current_session
 
 # ---------------------------------------------------------------- lanes
 LANE_GENERIC = 0   # plain at()/after() events (completions, timers, ...)
@@ -61,89 +62,6 @@ _CURSOR_LANES = (LANE_ARRIVAL, LANE_FAULT, LANE_REWIRE)
 _SLOT_LANES = (LANE_NET, LANE_TICK, LANE_CLOCK, LANE_ROLE)
 
 _INF = float("inf")
-
-# ------------------------------------------------------------- profiling
-
-
-class ProfileSession:
-    """Per-lane / per-handler cumulative dispatch time for one profiled run.
-
-    Each event loop binds the session active at its construction, so
-    back-to-back benchmark arms in one process each debit their own
-    session — ``select``-lane credit cannot leak across runs the way the
-    old module-global accumulator allowed.  ``rows``: (lane name,
-    handler qualname) -> [count, seconds]; the select accumulator lets
-    run loops debit scheduler-select time from the owning handler's row
-    and credit a dedicated ("select", ...) row instead."""
-
-    __slots__ = ("rows", "select_s", "select_n")
-
-    def __init__(self) -> None:
-        self.rows: dict[tuple[str, str], list] = {}
-        self.select_s = 0.0
-        self.select_n = 0
-
-    def note_select(self, seconds: float, name: str = "scheduler.select") -> None:
-        self.select_s += seconds
-        self.select_n += 1
-        key = ("select", name)
-        ent = self.rows.get(key)
-        if ent is None:
-            self.rows[key] = [1, seconds]
-        else:
-            ent[0] += 1
-            ent[1] += seconds
-
-    def add(self, lane: str, handler: str, dt: float) -> None:
-        key = (lane, handler)
-        ent = self.rows.get(key)
-        if ent is None:
-            self.rows[key] = [1, dt]
-        else:
-            ent[0] += 1
-            ent[1] += dt
-
-    def profile_rows(self) -> list[dict]:
-        rows = [
-            dict(lane=lane, handler=handler, events=cnt, seconds=sec,
-                 us_per_event=sec / cnt * 1e6 if cnt else 0.0)
-            for (lane, handler), (cnt, sec) in self.rows.items()
-        ]
-        rows.sort(key=lambda r: -r["seconds"])
-        return rows
-
-
-# The session new loops bind (``benchmarks/run.py --profile`` enables one
-# for the whole process; tests create scoped ones per run).
-_CURRENT: ProfileSession | None = None
-
-
-def enable_profiling(on: bool = True) -> ProfileSession | None:
-    """Start a fresh process-wide ProfileSession (or stop profiling).
-
-    Returns the new session; loops constructed while it is current bind
-    it for their lifetime, so re-enabling mid-process starts clean totals
-    without retroactively crediting already-running loops."""
-    global _CURRENT
-    _CURRENT = ProfileSession() if on else None
-    return _CURRENT
-
-
-def note_select(seconds: float, name: str = "scheduler.select") -> None:
-    """Report one scheduler-select's wall time to the current session.
-
-    Compat shim — the simulator reports through its own loop's
-    ``note_select`` so credit lands in the session that loop debits."""
-    if _CURRENT is not None:
-        _CURRENT.note_select(seconds, name)
-
-
-def profile_rows() -> list[dict]:
-    """Current session's dispatch profile as CSV-ready rows (slowest first)."""
-    if _CURRENT is None:
-        return []
-    return _CURRENT.profile_rows()
-
 
 def _handler_name(fn) -> str:
     return getattr(fn, "__qualname__", None) or repr(fn)
@@ -187,7 +105,7 @@ class EventLoop:
         self.now = 0.0
         self.processed = 0
         self._live = 0  # pending non-cancelled events (O(1) empty())
-        self.profile = _CURRENT  # ProfileSession bound for this loop's life
+        self.profile = current_session()  # bound for this loop's life
         # Single-slot lanes: lane -> (requested_time, Event).  The event is
         # consumed in-place by run() (cancelled=True), so arm() after a
         # fire re-arms without a cancel — the behaviour the old per-site
@@ -297,11 +215,6 @@ class EventLoop:
             self.trace_log.extend((t, lane) for t in times)
 
     # ------------------------------------------------------------------ run
-    def note_select(self, seconds: float, name: str = "scheduler.select") -> None:
-        """Report one scheduler-select's wall time to this loop's session."""
-        if self.profile is not None:
-            self.profile.note_select(seconds, name)
-
     def run(self, until: float = float("inf"), max_events: int = 50_000_000) -> None:
         log = self.trace_log
         prof = self.profile
@@ -325,14 +238,10 @@ class EventLoop:
             if prof is None:
                 ev.fn(self.now)
             else:
-                t0 = _time.perf_counter()
-                s0 = prof.select_s
-                ev.fn(self.now)
-                # Debit scheduler-select time reported via note_select():
-                # it is credited to the dedicated ("select", ...) row, so
-                # the owning handler's row shows event plumbing only.
-                dt = _time.perf_counter() - t0 - (prof.select_s - s0)
-                prof.add(LANE_NAMES[ev.lane], _handler_name(ev.fn), dt)
+                # The handler's row keeps its self time: the program spans
+                # inside it (select, waterfill, ...) take rows of their own.
+                with prof.dispatch(LANE_NAMES[ev.lane], _handler_name(ev.fn)):
+                    ev.fn(self.now)
         if self._heap and self.processed >= max_events:
             raise RuntimeError("event budget exhausted — runaway simulation?")
 
@@ -390,7 +299,7 @@ class EventPlane:
         self.processed = 0
         self._live = 0
         self._until = _INF
-        self.profile = _CURRENT  # ProfileSession bound for this loop's life
+        self.profile = current_session()  # bound for this loop's life
         # generic lane: Event heap + live-in-heap counter for compaction
         self._gen: list[Event] = []
         self._gen_live = 0
@@ -600,11 +509,6 @@ class EventPlane:
                 last = entry[0]
         buf.clear()
 
-    def note_select(self, seconds: float, name: str = "scheduler.select") -> None:
-        """Report one scheduler-select's wall time to this loop's session."""
-        if self.profile is not None:
-            self.profile.note_select(seconds, name)
-
     # ------------------------------------------------------------------ run
     def run(self, until: float = float("inf"), max_events: int = 50_000_000) -> None:
         self._until = until
@@ -660,33 +564,28 @@ class EventPlane:
             self._live -= 1
             if log_on:
                 self.trace_log.append((best_t, lane))
-            if prof is not None:
-                t0 = _time.perf_counter()
-                s0 = prof.select_s
             if lane == LANE_GENERIC:
                 ev = heapq.heappop(gen)
                 ev.cancelled = True         # consumed: late cancel is a no-op
                 self._gen_live -= 1
-                fn = ev.fn
-                fn(best_t)
+                fn, args = ev.fn, (best_t,)
             elif lane < LANE_NET:
                 pos = cur_pos[lane]
                 cur_pos[lane] = pos + 1
-                fn = self._cur_fn[lane]
-                fn(self._cur_p[lane][pos], best_t)
+                fn, args = self._cur_fn[lane], (self._cur_p[lane][pos], best_t)
             elif lane < LANE_PREFILL:
                 slot = slots[lane]
                 slots[lane] = None
-                fn = slot[3]
-                fn(best_t)
+                fn, args = slot[3], (best_t,)
             else:
                 m = heapq.heappop(ms)
-                fn = m[3]
-                fn(m[2], best_t)
-            if prof is not None:
-                # Same select-time debit as the reference loop (see above).
-                dt = _time.perf_counter() - t0 - (prof.select_s - s0)
-                prof.add(LANE_NAMES[lane], _handler_name(fn), dt)
+                fn, args = m[3], (m[2], best_t)
+            if prof is None:
+                fn(*args)
+            else:
+                # Same per-handler self-time row as the reference loop.
+                with prof.dispatch(LANE_NAMES[lane], _handler_name(fn)):
+                    fn(*args)
             if self._batch_buf:
                 self._flush_batch_log()
         if self.processed >= max_events and self._pending():

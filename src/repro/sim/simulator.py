@@ -29,7 +29,6 @@ transfers start, then one union dirty-component rate recompute runs
 from __future__ import annotations
 
 import dataclasses
-import time as _time
 from typing import Sequence
 
 import numpy as np
@@ -50,6 +49,7 @@ from repro.core.multihop import NetKVMultiHop, StagingStore
 from repro.core.view import ClusterView, ROLE_DECODE, ROLE_PREFILL
 from repro.cluster.network import BackgroundTraffic, FlowPlane, Transfer
 from repro.cluster.topology import FatTree, make_instances
+from repro.profiling import span
 from repro.traces.mooncake import Request
 from .engine import (
     LANE_ARRIVAL,
@@ -417,12 +417,10 @@ class Simulation:
         info = self._make_info(rs, False)
         if self.trace is not None:
             self.trace.now = now
-        t0 = _time.perf_counter()
-        decision = self.sched.select_deflected(
-            info, self.view, self.engine.deflect_eta_row(now))
-        dt = _time.perf_counter() - t0
-        self.decision_latencies.append(dt)
-        self.loop.note_select(dt)
+        with span("select") as sp:
+            decision = self.sched.select_deflected(
+                info, self.view, self.engine.deflect_eta_row(now))
+        self.decision_latencies.append(sp.duration)
         if decision is None:
             return False
         iid = decision.instance_id
@@ -674,12 +672,10 @@ class Simulation:
             self.sched.observe_request(req.block_hashes)
         if self.trace is not None:
             self.trace.now = now
-        t0 = _time.perf_counter()
-        decision = self.sched.select(info, rs.prefill_instance, self.view, view,
-                                     self.inflight)
-        dt = _time.perf_counter() - t0
-        self.decision_latencies.append(dt)
-        self.loop.note_select(dt)
+        with span("select") as sp:
+            decision = self.sched.select(info, rs.prefill_instance, self.view,
+                                         view, self.inflight)
+        self.decision_latencies.append(sp.duration)
         if decision is None:
             rs.rejected = True
             self.rejected += 1
@@ -700,12 +696,13 @@ class Simulation:
         """
         H = self.engine.hit_rows(reqs)
         view = self.oracle.view(now)
-        return self.sched.select_cohort(
-            items, self.view, view, self.inflight,
-            hit_matrix=H,
-            hit_fn=lambda r, iid: self.engine.hit_tokens(iid, reqs[r]),
-            evictions_fn=self.engine.evictions_of,
-        )
+        with span("select"):
+            return self.sched.select_cohort(
+                items, self.view, view, self.inflight,
+                hit_matrix=H,
+                hit_fn=lambda r, iid: self.engine.hit_tokens(iid, reqs[r]),
+                evictions_fn=self.engine.evictions_of,
+            )
 
     def _schedule_row(self, sel, k: int, rs: RequestState, now: float,
                       streaming: bool = False) -> None:
@@ -714,11 +711,9 @@ class Simulation:
         latency so the per-decision metric stays comparable."""
         if self.trace is not None:
             self.trace.now = now
-        t0 = _time.perf_counter()
-        decision = sel.select_row(k)
-        dt = (_time.perf_counter() - t0) + sel.take_setup_time()
-        self.decision_latencies.append(dt)
-        self.loop.note_select(dt)
+        with span("select") as sp:
+            decision = sel.select_row(k)
+        self.decision_latencies.append(sp.duration + sel.take_setup_time())
         if decision is None:
             rs.rejected = True
             self.rejected += 1
@@ -814,12 +809,10 @@ class Simulation:
         view = self.oracle.view(now)
         if self.trace is not None:
             self.trace.now = now
-        t0 = _time.perf_counter()
-        decisions = self.sched.select_batch(reqs, (self.view, hit_matrix), view,
-                                            self.inflight)
-        dt = _time.perf_counter() - t0
-        self.decision_latencies.append(dt / len(window))
-        self.loop.note_select(dt)
+        with span("select") as sp:
+            decisions = self.sched.select_batch(
+                reqs, (self.view, hit_matrix), view, self.inflight)
+        self.decision_latencies.append(sp.duration / len(window))
         # Arrival epoch: the whole dispatch burst lands at one timestamp, so
         # the FlowPlane admits it with a single union rate recompute.
         self.net.begin_epoch()

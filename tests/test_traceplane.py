@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import math
+import time
 
 import pytest
 
@@ -19,7 +20,8 @@ from repro.sim import (
     FaultEvent, RewireEvent, SimConfig, Simulation, TracePlane,
     enable_tracing, trace_session, ttft_breakdown_rows,
 )
-from repro.sim.engine import enable_profiling, make_event_loop, profile_rows
+from repro.profiling import enable_profiling, profile_rows, span
+from repro.sim.engine import make_event_loop
 from repro.sim.trace import BREAKDOWN_COLUMNS, FORENSICS_COLUMNS
 from repro.traces import generate_trace
 
@@ -239,6 +241,9 @@ class TestProfileSession:
             _drive(0, dict(**GPU64, background=0.2), trace=False)
             rows = profile_rows()
             assert rows, "profiling produced no rows"
+            # Decisions and water-fills are span rows, out of their handlers'.
+            spans = {r["handler"] for r in rows if r["lane"] == "span"}
+            assert {"select", "waterfill"} <= spans
             totals.append(sum(r["seconds"] for r in rows))
             assert rows == sess.profile_rows()
             enable_profiling(False)
@@ -247,11 +252,23 @@ class TestProfileSession:
         assert totals[1] < totals[0] * 1.7
 
     def test_loop_binds_session_at_construction(self):
-        sess = enable_profiling(True)
-        loop = make_event_loop("plane")
-        assert loop.profile is sess
-        enable_profiling(False)
-        assert make_event_loop("plane").profile is None
-        loop.note_select(0.25)
-        assert sess.select_s == pytest.approx(0.25)
-        assert profile_rows() == []  # module shim: no active session
+        for kind in ("plane", "reference"):
+            sess = enable_profiling(True)
+            loop = make_event_loop(kind)
+            assert loop.profile is sess
+            enable_profiling(False)
+            assert make_event_loop(kind).profile is None
+
+            def handler(now):
+                with span("select"):
+                    time.sleep(0.005)
+
+            loop.at(1.0, handler)
+            loop.run()
+            rows = {(r["lane"], r["handler"]): r for r in sess.profile_rows()}
+            sel = rows[("span", "select")]
+            own = rows[("generic", handler.__qualname__)]
+            assert sel["events"] == own["events"] == 1
+            # The handler's row is its self time: the span's 5 ms is debited.
+            assert sel["seconds"] >= 0.005 > own["seconds"]
+            assert profile_rows() == []  # no active session
