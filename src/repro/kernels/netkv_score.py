@@ -6,8 +6,10 @@ elementwise math with the masked argmin reduction in a single VMEM pass.
 Tier lookups use a one-hot contraction over the 4 tiers (no gather).
 
 Candidates are padded to a multiple of 128 lanes; padding is masked
-infeasible.  Scalars (s_r, l_r, iter model, m_min, beta_max) ride SMEM
-scalar prefetch.
+infeasible.  The iter model, m_min, beta_max and the real candidate count
+ride SMEM scalar prefetch; the per-request (s_r, l_r) ride a rowed block.
+The inputs are packed on the host in NumPy and the compiled program is
+built once per (rows, lanes) shape, so a warmed call compiles nothing.
 """
 
 from __future__ import annotations
@@ -41,29 +43,30 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
     to fuse/FMA differently; one shared program is not).
     """
     with span("score.prepare"):
-        hit_rows = jnp.asarray(hit_tokens, jnp.float32).reshape(1, -1)
-        tier_rows = jnp.asarray(tier, jnp.int32).reshape(1, -1)
-        infl_rows = jnp.asarray(n_inflight, jnp.float32).reshape(1, 4)
+        hit_rows = np.asarray(hit_tokens, np.float32).reshape(1, -1)
+        tier_rows = np.asarray(tier, np.int32).reshape(1, -1)
+        infl_rows = np.asarray(n_inflight, np.float32).reshape(1, 4)
     costs, best = netkv_score_cohort(
         free_mem, queued, batch, hit_rows, tier_rows,
         healthy, iter_scale, tier_bw, tier_lat, congestion, infl_rows,
         s_r=[s_r], input_len=[input_len], iter_a=iter_a, iter_b=iter_b,
         m_min=m_min, beta_max=beta_max, interpret=interpret,
     )
-    return costs[0], best[0]
+    return _first_row(costs, best)
 
 
-def _score_cohort_kernel(scal_ref, free_ref, queued_ref, batch_ref, hit_ref,
-                         tier_ref, healthy_ref, scale_ref, rscal_ref, bw_ref,
-                         lat_ref, cong_ref, infl_ref, cost_ref, best_ref,
-                         *, n_real: int):
+def _score_cohort_kernel(scal_ref, nreal_ref, free_ref, queued_ref, batch_ref,
+                         hit_ref, tier_ref, healthy_ref, scale_ref, rscal_ref,
+                         bw_ref, lat_ref, cong_ref, infl_ref, cost_ref, best_ref):
     """One grid step per cohort row: Eq. (2)-(7) + masked argmin, with the
     per-request scalars (s_r, l_r) riding a rowed block — row i is
     bit-identical to a single-row ``netkv_score`` call on the same snapshot.
     The per-row scalars deliberately arrive as a *block* rather than as
     ``scal_ref[base + program_id]``: a traced gather index changes XLA's
     fusion/FMA decisions for everything downstream, which costs bit-parity
-    across cohort sizes (observed as 1-ulp cost drift off-TPU).
+    across cohort sizes (observed as 1-ulp cost drift off-TPU).  The real
+    candidate count D rides scalar prefetch, so the program depends only on
+    the padded shape.
 
     Rowed operands are ``(rows, 1, lanes)`` with ``(1, 1, lanes)`` blocks:
     Mosaic needs a block's last two dims to tile (8, 128) or to equal the
@@ -102,10 +105,127 @@ def _score_cohort_kernel(scal_ref, free_ref, queued_ref, batch_ref, hit_ref,
     cost = t_xfer + t_queue + t_dec                                      # Eq. (5)
     lane = jax.lax.broadcasted_iota(jnp.int32, cost.shape, 1)
     feasible = ((healthy_ref[...] > f32(0.5)) & (free_ref[...] >= s_eff + m_min)
-                & (lane < jnp.int32(n_real)))
+                & (lane < nreal_ref[0]))
     cost = jnp.where(feasible, cost, f32(BIG))
     cost_ref[0] = cost
     best_ref[0, 0, 0] = jax.lax.argmin(cost[0], 0, jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _cohort_program(rows: int, lanes: int, interpret: bool):
+    """The scorer's compiled program for ``rows`` cohort rows over ``lanes``
+    padded candidates, built once per shape.  Its operands are the two
+    buffers :func:`_prepare` packs on the host; slicing them apart happens
+    inside the program.  Returns the kernel's own outputs: (rows, 1, lanes)
+    f32 costs and (rows, 1, 1) int32 winners."""
+    shared = pl.BlockSpec((1, lanes), lambda i, s, n: (_I0, _I0))
+    row_blk = pl.BlockSpec((1, 1, lanes), lambda i, s, n: (i, _I0, _I0))
+    row4 = pl.BlockSpec((1, 1, 4), lambda i, s, n: (i, _I0, _I0))
+    tier4 = pl.BlockSpec((1, 4), lambda i, s, n: (_I0, _I0))
+    score = pl.pallas_call(
+        _score_cohort_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows,),
+            in_specs=[shared, shared, shared, row_blk, row_blk, shared, shared,
+                      row4, tier4, tier4, tier4, row4],
+            out_specs=[
+                row_blk,
+                pl.BlockSpec((1, 1, 1), lambda i, s, n: (i, _I0, _I0),
+                             memory_space=pltpu.SMEM),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, 1, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((rows, 1, 1), jnp.int32),
+        ],
+        interpret=interpret,
+    )
+
+    # XLA names the custom call after the function it is traced in.  This
+    # one keeps the name an eager call gets, which is how the kernel is
+    # found in a device trace.
+    def tpu_custom_call(flt, ints):
+        pool, tiers, hit, tier, per_row, n_real = _operands(flt, ints, rows, lanes)
+        return score(tiers[3], n_real, pool[0:1], pool[1:2], pool[2:3], hit,
+                     tier, pool[3:4], pool[4:5], per_row[:, :, :4],
+                     tiers[0:1], tiers[1:2], tiers[2:3], per_row[:, :, 4:])
+
+    return jax.jit(tpu_custom_call)
+
+
+def _operands(flt, ints, rows: int, lanes: int):
+    """The program's operands as views of its two packed buffers, the f32
+    ``flt`` and the int32 ``ints`` (NumPy views on the host, slices inside
+    the program):
+
+    * ``pool`` (5, lanes): free_mem, queued, batch, healthy, iter_scale;
+    * ``tiers`` (4, 4): tier_bw, tier_lat, congestion, and the scalars
+      (iter_a, iter_b, m_min, beta_max);
+    * ``hit`` (rows, 1, lanes), and ``tier`` (rows, 1, lanes) in ``ints``;
+    * ``per_row`` (rows, 1, 8): (s_r, input_len, 0, 0) and the row's four
+      in-flight counts;
+    * ``n_real`` (1,) int32: D, the lanes that hold candidates.
+    """
+    n = rows * lanes
+    pool_end = 5 * lanes
+    hit_end = pool_end + n
+    row_end = hit_end + 8 * rows
+    return (flt[:pool_end].reshape(5, lanes),
+            flt[row_end:row_end + 16].reshape(4, 4),
+            flt[pool_end:hit_end].reshape(rows, 1, lanes),
+            ints[:n].reshape(rows, 1, lanes),
+            flt[hit_end:row_end].reshape(rows, 1, 8),
+            ints[n:n + 1])
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _trim(costs, best, rows: int, d: int):
+    """The program's padded outputs cut to the caller's R rows and D lanes."""
+    return costs[:rows, 0, :d], best[:rows, 0, 0]
+
+
+@jax.jit
+def _first_row(costs, best):
+    """Row 0 of a one-row cohort's outputs, in one dispatch."""
+    return costs[0], best[0]
+
+
+def _prepare(free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
+             tier_bw, tier_lat, congestion, infl_rows, s_r, input_len, iter_a,
+             iter_b, m_min, beta_max):
+    """(rows, lanes, flt, ints): the program's shape and its two packed
+    operand buffers (:func:`_operands`), filled on the host in NumPy: no
+    device op runs, and a call moves two arrays.
+
+    Lanes past D are zero (the kernel masks them).  An R=1 cohort becomes
+    two identical rows: a grid of one step unrolls the body, and XLA fuses
+    the unrolled program differently than the rows >= 2 grid loop
+    (ulp-level cost drift), so every call, any cohort size and the
+    single-row ``netkv_score`` wrapper, runs the same loop program.
+    """
+    f32 = np.float32
+    hit_rows = np.asarray(hit_rows, f32)
+    r, d = hit_rows.shape
+    rows = max(r, 2)
+    lanes = -(-d // LANES) * LANES
+    flt = np.zeros((5 + rows) * lanes + 8 * rows + 16, f32)
+    ints = np.zeros(rows * lanes + 1, np.int32)
+    pool, tiers, hit, tier, per_row, n_real = _operands(flt, ints, rows, lanes)
+
+    for k, col in enumerate((free_mem, queued, batch, healthy, iter_scale)):
+        pool[k, :d] = np.asarray(col, f32)
+    tiers[:] = np.asarray([tier_bw, tier_lat, congestion,
+                           [iter_a, iter_b, m_min, float(beta_max)]], f32)
+    hit[:r, 0, :d] = hit_rows
+    tier[:r, 0, :d] = np.asarray(tier_rows, np.int32)
+    per_row[:r, 0, 0] = np.asarray(s_r, f32).reshape(r)
+    per_row[:r, 0, 1] = np.asarray(input_len, f32).reshape(r)
+    per_row[:r, 0, 4:] = np.asarray(infl_rows, f32).reshape(r, 4)
+    if r == 1:
+        hit[1], tier[1], per_row[1] = hit[0], tier[0], per_row[0]
+    n_real[0] = d
+    return rows, lanes, flt, ints
 
 
 def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
@@ -120,8 +240,11 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
     ``input_len`` are per-row (self-contention and KV size vary with the
     prefill source).  Returns (costs (R, D), best (R,)) where row i matches
     a single-row ``netkv_score`` call bit-for-bit (same f32 op sequence,
-    grid-stepped over the cohort axis).  ``numpy=True`` routes through the
-    f32 NumPy twin — the fallback when no XLA backend is usable.
+    grid-stepped over the cohort axis).  The inputs are packed on the host
+    and scored by one compiled program per padded shape
+    (:func:`_cohort_program`); a call at a shape seen before compiles
+    nothing.  ``numpy=True`` routes through the f32 NumPy twin — the
+    fallback when no XLA backend is usable.
     """
     if numpy:
         return _netkv_score_cohort_np(
@@ -130,77 +253,14 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
             input_len=input_len, iter_a=iter_a, iter_b=iter_b, m_min=m_min,
             beta_max=beta_max)
     with span("score.prepare"):
-        r, d = hit_rows.shape[0], free_mem.shape[0]
-        dp = -(-d // LANES) * LANES
-        pad = dp - d
-
-        hit_rows = jnp.asarray(hit_rows, jnp.float32)
-        tier_rows = jnp.asarray(tier_rows, jnp.int32)
-        infl_rows = jnp.asarray(infl_rows, jnp.float32).reshape(r, 4)
-        s_rv = jnp.asarray(s_r, jnp.float32).reshape(r)
-        l_rv = jnp.asarray(input_len, jnp.float32).reshape(r)
-        rq = r
-        if r == 1:
-            # grid=(1,) unrolls the body and XLA fuses the unrolled program
-            # differently than the r>=2 grid loop (ulp-level cost drift).  Pad
-            # to two identical rows so every call — any cohort size, and the
-            # single-row ``netkv_score`` wrapper — runs the same loop program.
-            hit_rows = jnp.concatenate([hit_rows, hit_rows])
-            tier_rows = jnp.concatenate([tier_rows, tier_rows])
-            infl_rows = jnp.concatenate([infl_rows, infl_rows])
-            s_rv = jnp.concatenate([s_rv, s_rv])
-            l_rv = jnp.concatenate([l_rv, l_rv])
-            r = 2
-
-        def prep(x, dtype=jnp.float32):
-            x = jnp.asarray(x, dtype)
-            if pad:
-                x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-            return x.reshape(-1, dp)
-
-        def rowed(x, dtype=jnp.float32):
-            return prep(x, dtype).reshape(r, 1, dp)
-
-        f32 = jnp.float32
-        scal = jnp.stack([jnp.asarray(v, f32) for v in
-                          (iter_a, iter_b, m_min, float(beta_max))])
-        rscal = jnp.stack([s_rv, l_rv, jnp.zeros(r, f32), jnp.zeros(r, f32)],
-                          axis=1).reshape(r, 1, 4)
-        args = (
-            scal,
-            prep(free_mem), prep(queued), prep(batch), rowed(hit_rows),
-            rowed(tier_rows, jnp.int32), prep(healthy), prep(iter_scale), rscal,
-            jnp.asarray(tier_bw, f32).reshape(1, 4),
-            jnp.asarray(tier_lat, f32).reshape(1, 4),
-            jnp.asarray(congestion, f32).reshape(1, 4),
-            infl_rows.reshape(r, 1, 4),
-        )
+        r, d = np.shape(hit_rows)
+        rows, lanes, flt, ints = _prepare(
+            free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
+            tier_bw, tier_lat, congestion, infl_rows, s_r, input_len, iter_a,
+            iter_b, m_min, beta_max)
     with span("score.call"):
-        kernel = functools.partial(_score_cohort_kernel, n_real=d)
-        shared = pl.BlockSpec((1, dp), lambda i, s: (_I0, _I0))
-        row_blk = pl.BlockSpec((1, 1, dp), lambda i, s: (i, _I0, _I0))
-        row4 = pl.BlockSpec((1, 1, 4), lambda i, s: (i, _I0, _I0))
-        costs, best = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(r,),
-                in_specs=[shared, shared, shared, row_blk, row_blk, shared, shared,
-                          row4]
-                + [pl.BlockSpec((1, 4), lambda i, s: (_I0, _I0))] * 3 + [row4],
-                out_specs=[
-                    row_blk,
-                    pl.BlockSpec((1, 1, 1), lambda i, s: (i, _I0, _I0),
-                                 memory_space=pltpu.SMEM),
-                ],
-            ),
-            out_shape=[
-                jax.ShapeDtypeStruct((r, 1, dp), f32),
-                jax.ShapeDtypeStruct((r, 1, 1), jnp.int32),
-            ],
-            interpret=interpret,
-        )(*args)
-        return costs[:rq, 0, :d], best[:rq, 0, 0]
+        costs, best = _cohort_program(rows, lanes, interpret)(flt, ints)
+        return _trim(costs, best, r, d)
 
 
 def _netkv_score_cohort_np(free_mem, queued, batch, hit_rows, tier_rows,

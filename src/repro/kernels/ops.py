@@ -12,6 +12,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from . import ref as _ref
 from .flash_decode import flash_decode as _flash_decode
@@ -51,35 +52,30 @@ def kv_unpack(pool, buf, block_table, *, force_reference: bool = False):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "s_r", "input_len", "iter_a", "iter_b", "m_min", "beta_max", "force_reference"))
-def _netkv_score_jit(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
+    "s_r", "input_len", "iter_a", "iter_b", "m_min", "beta_max"))
+def _netkv_score_ref(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                      tier_bw, tier_lat, congestion, n_inflight, *,
-                     s_r, input_len, iter_a, iter_b, m_min, beta_max,
-                     force_reference):
-    kw = dict(s_r=s_r, input_len=input_len, iter_a=iter_a, iter_b=iter_b,
-              m_min=m_min, beta_max=beta_max)
-    if force_reference:
-        return _ref.netkv_score_ref(
-            free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
-            tier_bw, tier_lat, congestion, n_inflight, **kw)
-    return _netkv_score(
+                     s_r, input_len, iter_a, iter_b, m_min, beta_max):
+    return _ref.netkv_score_ref(
         free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
-        tier_bw, tier_lat, congestion, n_inflight,
-        interpret=interpret_mode(), **kw)
+        tier_bw, tier_lat, congestion, n_inflight, s_r=s_r,
+        input_len=input_len, iter_a=iter_a, iter_b=iter_b, m_min=m_min,
+        beta_max=beta_max)
 
 
 def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                 tier_bw, tier_lat, congestion, n_inflight, *,
                 s_r: float, input_len: float, iter_a: float, iter_b: float,
                 m_min: float, beta_max: int, force_reference: bool = False):
-    import jax.numpy as jnp
-
-    arrs = [jnp.asarray(a) for a in (free_mem, queued, batch, hit_tokens, tier,
-                                     healthy, iter_scale, tier_bw, tier_lat,
-                                     congestion, n_inflight)]
-    return _netkv_score_jit(*arrs, s_r=s_r, input_len=input_len, iter_a=iter_a,
-                            iter_b=iter_b, m_min=m_min, beta_max=beta_max,
-                            force_reference=force_reference)
+    """The kernel runs its own program, compiled once per padded shape, so
+    request sizes and scalars compile nothing new."""
+    kw = dict(s_r=s_r, input_len=input_len, iter_a=iter_a, iter_b=iter_b,
+              m_min=m_min, beta_max=beta_max)
+    arrs = (free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
+            tier_bw, tier_lat, congestion, n_inflight)
+    if force_reference:
+        return _netkv_score_ref(*[jnp.asarray(a) for a in arrs], **kw)
+    return _netkv_score(*arrs, interpret=interpret_mode(), **kw)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "force_reference"))

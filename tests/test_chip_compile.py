@@ -12,16 +12,18 @@ int32 reduction indices only.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_spec
 from repro.core.cost import B_TOK
 from repro.kernels.kv_pack import kv_pack, kv_unpack
-from repro.kernels.netkv_score import netkv_score_cohort
+from repro.kernels.netkv_score import _cohort_program, _prepare
 from repro.kernels.waterfill import _pallas_share_argmin, _pallas_shares
 
 
@@ -56,17 +58,20 @@ def no_compile_cache():
 
 
 def _score(r, d):
+    """The scorer's cached program on the operand shapes its host
+    preparation gives for an R x D cohort."""
     def build(one_chip):
-        f32, i32 = jnp.float32, jnp.int32
-        s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-        fn = functools.partial(
-            netkv_score_cohort, iter_a=0.0124, iter_b=1.6e-5, m_min=2e9,
-            beta_max=64, interpret=False)
-        args = ([s((d,), f32)] * 3 + [s((r, d), f32), s((r, d), i32)]
-                + [s((d,), f32)] * 2 + [s((4,), f32)] * 3 + [s((r, 4), f32)])
-        rows = [s((r,), f32), s((r,), f32)]
-        return (jax.jit(lambda *a: fn(*a[:-2], s_r=a[-2], input_len=a[-1])),
-                args + rows)
+        f32, i32 = np.float32, np.int32
+        args = _prepare(
+            np.zeros(d, f32), np.zeros(d, f32), np.zeros(d, f32),
+            np.zeros((r, d), f32), np.zeros((r, d), i32), np.zeros(d, f32),
+            np.zeros(d, f32), [0.0] * 4, [0.0] * 4, [0.0] * 4,
+            np.zeros((r, 4), f32), s_r=[0.0] * r, input_len=[0.0] * r,
+            iter_a=0.0124, iter_b=1.6e-5, m_min=2e9, beta_max=64)
+        rows, lanes, *bufs = args
+        return (_cohort_program(rows, lanes, False),
+                [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in bufs])
     return build
 
 
@@ -126,6 +131,17 @@ def test_kernel_compiles_for_v5e(name, x64, one_chip, no_compile_cache):
         fn, args = KERNELS[name](one_chip)
         text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("r", [1, 64])
+def test_scorer_keeps_its_trace_name(r, one_chip, no_compile_cache):
+    """A device trace names a Pallas kernel by its HLO instruction: the
+    scorer is the custom call named ``tpu_custom_call`` that returns
+    (f32[rows,1,lanes], s32[rows,1,1])."""
+    fn, args = _score(r, 1008)(one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert re.search(r"tpu_custom_call\S* = \(f32\[\d+,1,\d+\]\S* s32\[\d+,1,1\]",
+                     text)
 
 
 def test_interpret_mode_follows_backend():

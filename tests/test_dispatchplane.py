@@ -240,6 +240,104 @@ class TestCohortKernel:
                             backend="pallas")
 
 
+def _compiles() -> int:
+    """Backend compiles the span registry has seen, in any span or none."""
+    from repro.profiling import REGISTRY
+
+    return sum(len(ring) for ring in REGISTRY.backend.values())
+
+
+def _score_cohort(pool, rows, scal, **kw):
+    from repro.kernels.netkv_score import netkv_score_cohort
+
+    c, b = netkv_score_cohort(**pool, **rows, **scal, interpret=True, **kw)
+    return np.asarray(c), np.asarray(b)
+
+
+class TestScorerProgram:
+    """The scorer's compiled program is built once per (rows, lanes) shape
+    and reused: request sizes, the iteration model, the cohort size up to
+    two rows and the candidate count within one lane padding compile
+    nothing new."""
+
+    @pytest.mark.parametrize("field", ["s_r", "input_len", "iter_a", "iter_b",
+                                       "m_min", "beta_max"])
+    def test_rows_and_scalars_share_one_program(self, field):
+        from repro.kernels.netkv_score import _cohort_program
+
+        pool, rows2, scal = _kernel_args(2, 20, seed=21)
+        rows1 = {k: v[:1] for k, v in rows2.items()}
+        _score_cohort(pool, rows1, scal)
+        built = _cohort_program.cache_info().currsize
+        _score_cohort(pool, rows2, scal)       # R=2 runs R=1's program
+        assert _cohort_program.cache_info().currsize == built
+        n0 = _compiles()
+        for k in (2.0, 3.0):
+            for rows in (rows1, rows2):
+                rows, sc = dict(rows), dict(scal)
+                if field in rows:
+                    rows[field] = [k * v for v in rows[field]]
+                else:
+                    sc[field] = type(sc[field])(k * sc[field])
+                c, b = _score_cohort(pool, rows, sc)
+                c_n, b_n = _score_cohort(pool, rows, sc, numpy=True)
+                np.testing.assert_array_max_ulp(c, c_n, maxulp=1)
+        assert _compiles() == n0
+        assert _cohort_program.cache_info().currsize == built
+
+    def test_candidate_counts_in_one_padding_share_one_program(self):
+        from repro.kernels.netkv_score import BIG, _cohort_program, _prepare
+
+        pool, rows, scal = _kernel_args(2, 24, seed=22)
+        # Lanes 12-23 copy lanes 0-11 at half the iteration time, so each
+        # is strictly cheaper than its twin: the D=24 winners lie past 12.
+        for x in (*pool.values(), rows["hit_rows"], rows["tier_rows"]):
+            x[..., 12:] = x[..., :12]
+        pool["iter_scale"][12:] *= 0.5
+        c24, b24 = _score_cohort(pool, rows, scal)
+        assert (b24 >= 12).all()
+        built = _cohort_program.cache_info().currsize
+        cut = lambda x: {k: (v[..., :12] if isinstance(v, np.ndarray)
+                             and v.shape[-1] == 24 else v)
+                         for k, v in x.items()}
+        c12, b12 = _score_cohort(cut(pool), cut(rows), scal)
+        assert _cohort_program.cache_info().currsize == built
+        assert c12.shape == (2, 12) and (b12 < 12).all()
+        assert np.array_equal(c12, c24[:, :12])
+        # The program masks by its candidate-count operand, not by what
+        # the padding holds.
+        rows_, lanes, flt, ints = _prepare(
+            *[pool[k] for k in ("free_mem", "queued", "batch")],
+            rows["hit_rows"], rows["tier_rows"], pool["healthy"],
+            pool["iter_scale"], scal["tier_bw"], scal["tier_lat"],
+            scal["congestion"], rows["infl_rows"], rows["s_r"],
+            rows["input_len"], scal["iter_a"], scal["iter_b"], scal["m_min"],
+            scal["beta_max"])
+        ints[-1] = 12                          # the candidate count D
+        costs, best = _cohort_program(rows_, lanes, True)(flt, ints)
+        costs, best = np.asarray(costs)[:, 0], np.asarray(best)[:, 0, 0]
+        assert np.array_equal(costs[:, :12], c12)
+        assert (costs[:, 12:] == np.float32(BIG)).all()
+        assert np.array_equal(best, b12)
+
+    @pytest.mark.parametrize("r", [1, 2, 3, 4, 5])
+    def test_cached_rows_match_numpy_twin(self, r):
+        # Bit-for-bit on the data of test_numpy_twin_matches_kernel.  On
+        # other draws the interpreter may fuse iter_a + iter_b * x into one
+        # multiply-add and differ from the twin by an ulp; the winners agree.
+        pool, rows, scal = _kernel_args(r, 24, seed=12)
+        for _ in range(2):                     # built, then cached
+            c_k, b_k = _score_cohort(pool, rows, scal)
+            c_n, b_n = _score_cohort(pool, rows, scal, numpy=True)
+            assert np.array_equal(c_k, c_n) and np.array_equal(b_k, b_n)
+        for seed in range(8):
+            pool, rows, scal = _kernel_args(r, 24, seed=seed)
+            c_k, b_k = _score_cohort(pool, rows, scal)
+            c_n, b_n = _score_cohort(pool, rows, scal, numpy=True)
+            np.testing.assert_array_max_ulp(c_k, c_n, maxulp=1)
+            assert np.array_equal(b_k, b_n), seed
+
+
 # --------------------------------------------------------------------------
 # end-to-end layer: dispatch_mode="plane" vs "reference"
 # --------------------------------------------------------------------------

@@ -119,34 +119,43 @@ def test_compile_seconds_are_a_union_not_a_sum(clock):
     assert REGISTRY.backend_compiles("inner") == 0
 
 
-def _score_once(d=12):
+def _score_once(d=12, r=1):
     from repro.kernels.netkv_score import netkv_score_cohort
     from repro.kernels.ops import interpret_mode
 
     out = netkv_score_cohort(
         np.full(d, 1e12), np.zeros(d), np.zeros(d),
-        np.zeros((1, d), np.float32), np.zeros((1, d), np.int32),
+        np.zeros((r, d), np.float32), np.zeros((r, d), np.int32),
         np.ones(d), np.ones(d), [1e10] * 4, [1e-5] * 4, [0.0] * 4,
-        np.zeros((1, 4), np.float32), s_r=[1e9], input_len=[1024.0],
+        np.zeros((r, 4), np.float32), s_r=[1e9] * r, input_len=[1024.0] * r,
         iter_a=0.0124, iter_b=1.6e-5, m_min=2e9, beta_max=64,
         interpret=interpret_mode())
     return int(np.asarray(out[1])[0])
 
 
 def test_scorer_call_charges_its_backend_compile_once():
-    _score_once()                                    # eager pad/stack shapes
+    r = 11                                           # a cohort size no other test scores
     calls0 = REGISTRY.ended("score.call")
     n0 = REGISTRY.backend_compiles("score.call")
     p0 = REGISTRY.backend_compiles("score.prepare")
     with span("test.select"):
-        assert _score_once() == 0
+        assert _score_once(r=r) == 0
     assert REGISTRY.ended("score.call") == calls0 + 1
-    # The kernel is built anew per eager call: one backend compile, charged
-    # to the innermost span; the warmed input preparation compiles nothing.
-    assert REGISTRY.backend_compiles("score.call") == n0 + 1
+    # The first call at a new shape compiles the program and the cut of its
+    # padded outputs to (R, D), both charged to the innermost span; the host
+    # preparation compiles nothing.
+    assert REGISTRY.backend_compiles("score.call") == n0 + 2
     assert REGISTRY.backend_compiles("score.prepare") == p0
     (lo, hi), = REGISTRY.intervals("test.select")[-1:]
     assert 0 < REGISTRY.compile_seconds("test.select", lo, hi) <= hi - lo
+    # The same shape again is one call of the cached program.
+    with span("test.select"):
+        assert _score_once(r=r) == 0
+    assert REGISTRY.ended("score.call") == calls0 + 2
+    assert REGISTRY.backend_compiles("score.call") == n0 + 2
+    assert REGISTRY.backend_compiles("score.prepare") == p0
+    (lo, hi), = REGISTRY.intervals("test.select")[-1:]
+    assert REGISTRY.compile_seconds("test.select", lo, hi) == 0.0
 
 
 def test_cached_jit_call_charges_nothing():
@@ -214,7 +223,7 @@ def _host_events(path, prefix):
 
 
 def test_profiler_trace_holds_the_spans_on_its_clock(tmp_path):
-    _score_once()                                    # warm eager shapes
+    _score_once()                                    # warm the program
     REGISTRY.reset()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
