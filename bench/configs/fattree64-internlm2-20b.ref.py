@@ -20,7 +20,9 @@ operation is carried out in (float64 for the reference, bfloat16 for the
 control that stands for a lower-precision scorer).
 
 Bytes.  s_r = 2 * layers * KV heads * head dim * bytes * l_r (Eq. 1) and
-the transfer moves s_eff of Eq. (2) for the instance chosen.
+the transfer moves s_eff of Eq. (2) for the instance chosen
+(``fabric_bytes``, which the harness reads for every decision and for the
+mix's capacity model).
 
 Water-filling.  Every flow's rate grows at the same pace until a link it
 crosses is full; the flows of the link that fills first are fixed at its
@@ -72,6 +74,12 @@ def kv_bytes(kv: dict, input_len: float) -> float:
 def s_eff(s_r: float, hit: float, input_len: float) -> float:
     """Eq. (2): the bytes left to move after ``hit`` prefix tokens."""
     return s_r * (1.0 - min(hit, input_len) / max(input_len, 1.0))
+
+
+def fabric_bytes(kv: dict, input_len: float, hit_fraction: float) -> float:
+    """Bytes a request of ``input_len`` tokens puts on the fabric when
+    ``hit_fraction`` of its prompt is already on the chosen instance."""
+    return kv_bytes(kv, input_len) * (1.0 - hit_fraction)
 
 
 def waterfill(paths, caps, dtype=np.float64):

@@ -23,8 +23,3 @@ def netkv_score_flops(rows: int, d: int) -> int:
     (2), the feasibility compare and the argmin (3): 34 operations."""
     return 34 * rows * d
 
-
-def kv_bytes_per_token(cfg: dict) -> int:
-    """Eq. (1)'s coefficient: K and V of every layer, every KV head."""
-    return (2 * cfg["num_hidden_layers"] * cfg["num_key_value_heads"]
-            * cfg["head_dim"] * cfg["bytes_per_elem"])

@@ -13,8 +13,8 @@ window has closed, held against the plain references of the configuration
   Eq. (2)-(7) on the call's inputs;
 * every request: the decode instance it was sent to is the reference's
   best on the snapshot its decision was scored on, the bytes put on the
-  fabric for it are Eq. (1)-(2)'s for that instance, and it finishes with
-  its ``output_len`` tokens, its timestamps in order;
+  fabric for it are the reference's ``fabric_bytes`` for that instance,
+  and it finishes with its ``output_len`` tokens, its timestamps in order;
 * every water-filling pass of FlowPlane: the rates of all live flows
   against a plain max-min water-fill of their paths over the links'
   capacities.
@@ -22,13 +22,15 @@ window has closed, held against the plain references of the configuration
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import importlib
 import os
 import time
 
 import numpy as np
 
-from bench import harness, roofline, traffic
+from bench import harness, traffic
 
 # A kernel winner agrees with the reference when it is the reference's
 # winner or ties it within this relative cost (f32 kernel vs f64).
@@ -38,11 +40,26 @@ TIE_RTOL = 1e-5
 WARM_ROWS = (1, 3, 4)
 
 
+# ``ModelKVSpec`` fields the harness sets itself; a configuration's
+# ``kv_spec`` block may set any other.
+_SPEC_SET = ("name", "n_layers", "n_kv_heads", "d_head", "bytes_per_elem", "tp")
+
+
 def _sim_config(config: dict, seed: int):
+    """The program's configuration.  Its ``ModelKVSpec`` takes the four
+    published Eq. (1) keys of ``kv`` and every key of the optional
+    ``kv_spec`` block, passed on verbatim."""
     from repro.core.cost import IterTimeModel, ModelKVSpec, PrefillTimeModel
     from repro.sim import SimConfig
 
     topo, kv, a = config["topology"], config["kv"], config["assumed"]
+    extra = config.get("kv_spec", {})
+    fields = {f.name for f in dataclasses.fields(ModelKVSpec)}
+    for key in extra:
+        if key not in fields or key in _SPEC_SET:
+            raise harness.BenchError(
+                f"kv_spec key {key!r}: not a ModelKVSpec field the "
+                f"configuration may set ({sorted(fields - set(_SPEC_SET))})")
     return SimConfig(
         scheduler=config["scheduler"],
         scheduler_kwargs={"backend": config["backend"]},
@@ -56,7 +73,8 @@ def _sim_config(config: dict, seed: int):
         kv_spec=ModelKVSpec(name=config["model"], n_layers=kv["num_hidden_layers"],
                             n_kv_heads=kv["num_key_value_heads"],
                             d_head=kv["head_dim"],
-                            bytes_per_elem=kv["bytes_per_elem"], tp=config["tp"]),
+                            bytes_per_elem=kv["bytes_per_elem"], tp=config["tp"],
+                            **extra),
         iter_model=IterTimeModel(a=a["iter_a"], b=a["iter_b"]),
         prefill_model=PrefillTimeModel(c=a["prefill_c"], d=a["prefill_d"]),
         warmup=config["warmup"], measure=config["measure"], seed=seed)
@@ -68,17 +86,20 @@ def _n_decode(cfg: dict) -> int:
     return gpus // cfg["tp"] - cfg["n_prefill"]
 
 
-def deployment(cfg: dict) -> dict:
-    """The configuration's side of the mix's capacity model."""
+def deployment(cfg: dict, ref) -> dict:
+    """The configuration's side of the mix's capacity model; the bytes a
+    request puts on the fabric come from the reference ``ref``."""
     a = cfg["assumed"]
     return dict(n_prefill=cfg["n_prefill"], n_decode=_n_decode(cfg),
                 beta_max=cfg["beta_max"],
-                kv_bytes_per_token=roofline.kv_bytes_per_token(cfg["kv"]),
+                fabric_bytes=functools.partial(ref.fabric_bytes, cfg["kv"]),
                 iter_ab=(a["iter_a"], a["iter_b"]),
                 prefill_cd=(a["prefill_c"], a["prefill_d"]), **cfg["fabric"])
 
 
 def reference(config: dict):
+    """The configuration's plain reference: the file its ``reference`` key
+    names, relative to ``bench/configs``."""
     return harness.load_module(os.path.join(harness.BENCH, "configs",
                                             config["reference"]))
 
@@ -201,7 +222,7 @@ def setup(run: harness.Run) -> State:
         raise harness.BenchError(f"the sim system runs mooncake mixes, not "
                                  f"{mix.get('kind')!r}")
     a = cfg["assumed"]
-    st = State(traffic.MooncakeWindow(mix, deployment(cfg)))
+    st = State(traffic.MooncakeWindow(mix, deployment(cfg, reference(cfg))))
     run.counters["offered_rps"] = st.win.rps
     # Warm: one short simulation on a trace of its own, then the kernel at
     # the cohort sizes the short run may not have formed.
@@ -268,9 +289,15 @@ def free(run: harness.Run, st: State) -> None:
     st.win = None
 
 
+# Scorer keywords the reference reads under another name.
+_KW_NAMES = {"input_len": "l_r"}
+
+
 def kernel_rows(calls):
     """Per call: the inputs as f64 NumPy arrays and scalars, and the
-    kernel's cost rows and winners."""
+    kernel's cost rows and winners.  Every keyword of the call is in the
+    inputs under its own name (``input_len`` as ``l_r``): a per-row one
+    raveled, a scalar one as a float."""
     for args, kw, out in calls:
         cols = [np.asarray(a, np.float64) for a in args]
         r = cols[3].reshape(-1, cols[0].shape[0]).shape[0]
@@ -279,11 +306,10 @@ def kernel_rows(calls):
             hit=cols[3].reshape(r, -1), tier=cols[4].reshape(r, -1).astype(np.int64),
             healthy=cols[5], scale=cols[6], bw=cols[7].ravel(),
             lat=cols[8].ravel(), cong=cols[9].ravel(),
-            infl=cols[10].reshape(r, 4),
-            s_r=np.asarray(kw["s_r"], np.float64).ravel(),
-            l_r=np.asarray(kw["input_len"], np.float64).ravel(),
-            iter_a=float(kw["iter_a"]), iter_b=float(kw["iter_b"]),
-            m_min=float(kw["m_min"]), beta_max=float(kw["beta_max"]))
+            infl=cols[10].reshape(r, 4))
+        for name, v in kw.items():
+            v = np.asarray(v, np.float64)
+            inputs[_KW_NAMES.get(name, name)] = float(v) if v.ndim == 0 else v.ravel()
         costs, best = (np.asarray(o) for o in out)
         yield inputs, costs.reshape(r, -1).astype(np.float64), best.ravel()
 
@@ -358,8 +384,8 @@ def check_requests(ref, config, st: State, refs) -> dict:
                 nbytes += 1
                 continue
             l_r = float(rs.req.input_len)
-            want = ref.s_eff(ref.kv_bytes(config["kv"], l_r),
-                             float(inputs["hit"][row, j[0]]), l_r)
+            hit = min(float(inputs["hit"][row, j[0]]), l_r) / max(l_r, 1.0)
+            want = ref.fabric_bytes(config["kv"], l_r, hit)
             nbytes += int(abs(sent - want) >= 1.0)
     return {"decision_mismatch": decision, "bytes_mismatch": nbytes,
             "unserved": unserved}

@@ -24,12 +24,15 @@ def cell_parts(name):
     return bench, cell, config, mix, system
 
 
-def small_run(name, seed=12345678901234, seconds=2.0):
+def small_run(name, seed=12345678901234, seconds=2.0, config=None):
+    """A run of cell ``name``; ``config`` puts another configuration of
+    the same system in the cell's place."""
     import jax
 
     from bench.run import measure
 
-    bench, cell, config, mix, system = cell_parts(name)
+    bench, cell, cell_config, mix, system = cell_parts(name)
+    config = cell_config if config is None else config
     args = types.SimpleNamespace(seed=seed, seconds=seconds, trace=0)
     run, metrics, device, _ = measure(bench, cell, config, mix, system,
                                       jax.devices()[:1], args, check_device=False)

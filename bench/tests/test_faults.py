@@ -137,7 +137,9 @@ def test_sim_cohort_rows_are_checked(monkeypatch):
     run, _ = small_run(SIM, seconds=0.1)
     st = run.notes["state"]
     assert max(a[3].shape[0] for a, _, _ in st.calls) > 1
-    assert len(st.decided) == 8
+    # The window replays the burst trace once per simulation: 8 decisions each.
+    assert st.k >= 1 and len(st.decided) == 8 * st.k
+    assert {k for k, _ in st.decided} == set(range(st.k))
     assert correct(run), run.checks
 
     ns = importlib.import_module("repro.kernels.netkv_score")

@@ -1,6 +1,8 @@
 """The per-layer readers of the program's own spans and compile counts,
 on a test-size run of the simulator cell."""
 
+import math
+
 import pytest
 
 from bench.harness import BENCH, load_module
@@ -19,18 +21,19 @@ def read():
 
 def test_readers_find_the_program_spans(read):
     run, v = read
-    assert all(v[name] is not None for name in READERS), v
+    assert all(v[name] is not None and math.isfinite(v[name]) for name in READERS), v
     assert 0 < v["select_share.sim"] < 100
-    assert 0 < v["select_compile_share.sim"] <= 100
+    assert 0 <= v["select_compile_share.sim"] <= 100
     assert 0 < v["waterfill_span_share.sim"] < 100
 
 
 def test_compiles_per_call_matches_the_harness_count(read):
     run, v = read
-    # Every backend compile in the window is the scorer's, one per call
-    # (the eager kernel is rebuilt each call), give or take one.
+    # The scorer's compiles in the window, per call, account for every
+    # backend compile there, give or take one (none once set-up has warmed
+    # the scorer's shapes).
     calls = run.counters["kernel_calls"]
-    assert v["score_compiles_per_call.sim"] >= 1.0
+    assert calls > 0
     assert abs(v["score_compiles_per_call.sim"] * calls
                - run.counters["compiles_in_window"]) <= 1
 

@@ -14,6 +14,20 @@ KV = {"num_hidden_layers": 48, "num_key_value_heads": 8, "head_dim": 128,
       "bytes_per_elem": 2}
 
 
+def test_kv_bytes_eq1():
+    # Eq. (1): 2 * layers * kv heads * head dim * bytes, per token.
+    smol = {"num_hidden_layers": 30, "num_key_value_heads": 3, "head_dim": 64,
+            "bytes_per_elem": 2}
+    assert REF.kv_bytes(smol, 1) == 2 * 30 * 3 * 64 * 2 == 23040
+    assert REF.kv_bytes(KV, 1) == 196_608
+
+
+def test_fabric_bytes_is_eq1_eq2():
+    for l, hit in ((1000.0, 250.0), (4096.0, 0.0), (1000.0, 5000.0), (0.0, 0.0)):
+        frac = min(hit, l) / max(l, 1.0)
+        assert REF.fabric_bytes(KV, l, frac) == REF.s_eff(REF.kv_bytes(KV, l), hit, l)
+
+
 def test_eq1_eq2_bytes():
     assert REF.kv_bytes(KV, 1000) == 196_608_000.0
     assert REF.s_eff(1000.0, 250, 1000) == 750.0

@@ -110,3 +110,81 @@ def test_scorer_roofline_reads_the_kernel_as_the_chip_names_it():
     want = 100 * 2 * (4 * (5 * 12 + 3 * 12) / 819e9) / 4e-6
     assert share == pytest.approx(want)
     assert 0 < share < 100
+
+
+def _idle_by_host_scan(r, dev):
+    """The scan ``idle_by_host`` replaced: every host span for every gap."""
+    busy = trace_reduce.merged((o.start, o.end) for o in r.ops.get(dev, ()))
+    lo, hi = r.window
+    gaps, t = [], lo
+    for s, e in busy:
+        if s > t:
+            gaps.append((t, min(s, hi)))
+        t = max(t, e)
+    if t < hi:
+        gaps.append((t, hi))
+    out = {}
+    for s, e in gaps:
+        mid = 0.5 * (s + e)
+        label, width = "host", float("inf")
+        for name, hs, he in r.host:
+            if hs <= mid <= he and he - hs < width and name != "window":
+                label, width = name, he - hs
+        out[label] = out.get(label, 0.0) + (e - s)
+    return out
+
+
+def _nested_trace(rng, n_top):
+    """Simulations holding selections holding score calls, with water-fills
+    between them; device ops inside and between the spans; equal-length
+    twins and spans that end exactly on a gap's middle."""
+    host, ops, t = [], [], 0.0
+    names = ("select", "score.call", "score.readback", "waterfill")
+    for _ in range(n_top):
+        s0 = t
+        t += rng.uniform(1e-4, 1e-3)
+        for _ in range(int(rng.integers(1, 8))):
+            name = names[int(rng.integers(len(names)))]
+            a = t
+            t += rng.uniform(1e-5, 5e-4)
+            if rng.random() < 0.5:                   # a nested child
+                c0 = rng.uniform(a, t)
+                c1 = rng.uniform(c0, t)
+                host.append(("score.prepare", c0, c1))
+                if rng.random() < 0.2:               # an equal-length twin
+                    host.append(("score.call", c0, c1))
+            host.append((name, a, t))
+            if rng.random() < 0.7:
+                d0 = rng.uniform(a, t)
+                ops.append(trace_reduce.Op("fusion", d0, d0 + rng.uniform(0, 1e-4), ""))
+            t += rng.uniform(0, 2e-4)
+        host.append(("simulation", s0, t))
+        t += rng.uniform(0, 1e-3)
+    host.append(("window", 0.0, t))
+    rng.shuffle(host)
+    return trace_reduce.Reduced((0.0, t), {0: ops}, host)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_idle_by_host_sweep_matches_the_scan(seed):
+    import numpy as np
+
+    r = _nested_trace(np.random.default_rng(seed), 400)
+    assert len(r.host) > 2000 and len(r.ops[0]) > 1000
+    want = _idle_by_host_scan(r, 0)
+    assert r.idle_by_host(0) == want
+    assert len(want) > 3
+
+
+
+@pytest.mark.parametrize("edge", ["ends", "starts"])
+def test_idle_by_host_span_on_a_gaps_middle(edge):
+    """A span that ends, or starts, exactly on a gap's middle holds it."""
+    r = trace_reduce.reduce_planes(hand_trace(), n_devices=2)
+    busy = trace_reduce.merged((o.start, o.end) for o in r.ops[0])
+    s, e = busy[0][1], busy[1][0]                   # the 18 .. 40 ms gap
+    mid = 0.5 * (s + e)
+    span = (s, mid) if edge == "ends" else (mid, mid + 1e-3)
+    r.host.append((edge, *span))
+    assert r.idle_by_host(0) == _idle_by_host_scan(r, 0)
+    assert r.idle_by_host(0)[edge] == e - s

@@ -18,7 +18,7 @@ def deployment():
     cfg = harness.load_json(os.path.join(BENCH, "configs",
                                          "fattree64-internlm2-20b.json"))
     sim = harness.load_module(os.path.join(BENCH, "systems", "sim.py"))
-    return sim.deployment(cfg)
+    return sim.deployment(cfg, sim.reference(cfg))
 
 
 def test_mooncake_window_repeats():
@@ -51,7 +51,8 @@ def test_mooncake_copy_matches_the_program_generator():
     theirs = generate_trace("rag", duration=6.0, target_rps=3.0, seed=4)
     assert [(r.arrival, r.input_len, r.output_len, r.block_hashes) for r in mine] == \
            [(r.arrival, r.input_len, r.output_len, r.block_hashes) for r in theirs]
-    dep = dict(deployment(), kv_bytes_per_token=327_680.0)
+    dep = dict(deployment(),
+               fabric_bytes=lambda l, hit: 327_680.0 * l * (1.0 - hit))
     got = traffic.mooncake_capacity(prof, **dep, **mix("rag")["capacity_model"])
     assert np.isclose(got, profile_capacity("rag"), rtol=1e-12)
     assert PROFILES["rag"].p_share == prof["p_share"]

@@ -11,6 +11,7 @@ the same clock.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -85,7 +86,10 @@ class Reduced:
 
     def idle_by_host(self, dev: int) -> dict[str, float]:
         """Idle seconds of ``dev`` in the window, by the innermost
-        benchmark span open on the host at the middle of each gap."""
+        benchmark span open on the host at the middle of each gap: the
+        shortest span with start <= middle <= end, the first listed of
+        equal length.  One sweep: gaps come in time order, spans are added
+        as they start and dropped once they end before a gap's middle."""
         busy = merged((o.start, o.end) for o in self.ops.get(dev, ()))
         lo, hi = self.window
         gaps, t = [], lo
@@ -95,13 +99,20 @@ class Reduced:
             t = max(t, e)
         if t < hi:
             gaps.append((t, hi))
+        spans = sorted((hs, i, he, name) for i, (name, hs, he)
+                       in enumerate(self.host) if name != "window")
+        open_spans: list[tuple[float, int, float, str]] = []   # heap by width
+        k = 0
         out: dict[str, float] = {}
         for s, e in gaps:
             mid = 0.5 * (s + e)
-            label, width = "host", float("inf")
-            for name, hs, he in self.host:
-                if hs <= mid <= he and he - hs < width and name != "window":
-                    label, width = name, he - hs
+            while k < len(spans) and spans[k][0] <= mid:
+                hs, i, he, name = spans[k]
+                heapq.heappush(open_spans, (he - hs, i, he, name))
+                k += 1
+            while open_spans and open_spans[0][2] < mid:
+                heapq.heappop(open_spans)
+            label = open_spans[0][3] if open_spans else "host"
             out[label] = out.get(label, 0.0) + (e - s)
         return out
 

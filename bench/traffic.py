@@ -108,13 +108,15 @@ def mooncake_trace(prof: dict, *, duration: float, target_rps: float,
 
 
 def mooncake_capacity(prof: dict, *, n_prefill: int, n_decode: int,
-                      beta_max: int, kv_bytes_per_token: float,
+                      beta_max: int, fabric_bytes,
                       tor_egress_bytes_per_s: float,
                       agg_egress_bytes_per_s: float, tier3_frac: float,
                       background: float, headroom: float, iter_ab,
                       prefill_cd, seed: int = 0, n: int = 4000) -> float:
     """``profile_capacity`` of ``repro/traces/mooncake.py`` at 900a6150,
-    with the iteration and prefill models passed in."""
+    with the iteration and prefill models passed in.  The network term
+    divides by the bytes of a mean request, ``fabric_bytes(mean input,
+    mean hit fraction)``, the configuration's reference."""
     rng = np.random.default_rng(seed)
     mi = float(_input_lengths(rng, n, prof).mean())
     mo = float(np.clip(rng.lognormal(np.log(prof["out_median"]),
@@ -126,7 +128,7 @@ def mooncake_capacity(prof: dict, *, n_prefill: int, n_decode: int,
     c, d = prefill_cd
     prefill_rps = n_prefill / (c * mi + d)
     decode_rps = n_decode * beta_max / (mo * (a + b * beta_max))
-    mean_eff = kv_bytes_per_token * mi * (1.0 - prof["p_share"] * 0.55)
+    mean_eff = fabric_bytes(mi, prof["p_share"] * 0.55)
     net_rps = fabric * headroom / max(mean_eff, 1.0)
     return min(prefill_rps, decode_rps, net_rps)
 
@@ -135,8 +137,8 @@ class MooncakeWindow:
     """Simulations for a window: trace ``k`` is base trace ``k`` of the mix,
     generated when the window first asks for it.  ``deployment`` holds the
     configuration's side of the capacity model: ``n_prefill``,
-    ``n_decode``, ``beta_max``, ``kv_bytes_per_token``, the fabric egress
-    and the iteration and prefill models."""
+    ``n_decode``, ``beta_max``, ``fabric_bytes``, the fabric egress and
+    the iteration and prefill models."""
 
     def __init__(self, mix: dict, deployment: dict):
         self.mix = mix
