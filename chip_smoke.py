@@ -128,9 +128,11 @@ def check_kernel_call(args, kw, out) -> tuple[int, int, float]:
     t_d = scale * v_iter_time(model, batch + 1)
     s_r = np.ravel(kw["s_r"])
     l_r = np.ravel(kw["input_len"])
+    state = np.zeros_like(s_r) if kw.get("state_bytes") is None \
+        else np.ravel(kw["state_bytes"])
     agree, err = 0, 0.0
     for i in range(hit_rows.shape[0]):
-        s_eff = v_s_eff(float(s_r[i]), hit_rows[i], int(l_r[i]))
+        s_eff = v_s_eff(float(s_r[i]), hit_rows[i], int(l_r[i]), float(state[i]))
         ok = (healthy > 0.5) & (free >= s_eff + kw["m_min"])
         t_x = v_transfer_time(s_eff, tier_rows[i].astype(np.int64), bw, cong,
                               infl_rows[i], lat)

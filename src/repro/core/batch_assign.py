@@ -87,7 +87,7 @@ class NetKVBatch(NetKVFull):
         vinflight: dict[tuple[int, int], int] = {}
         # Request-side constants: s_eff rows and tier rows.
         s_eff_rows = np.stack([
-            v_s_eff(req.kv_bytes, hits[i], req.input_len)
+            v_s_eff(req.kv_bytes, hits[i], req.input_len, req.state_bytes)
             for i, (req, _) in enumerate(reqs)
         ])
         tier_rows = [cv.tier_row(pid) for _, pid in reqs]

@@ -4,6 +4,7 @@ Implements Eqs. (1)-(7) of the paper:
 
   (1) KV cache size          s_r = 2 * n_layers * n_kv_heads * d_head * l_r * b_elem
   (2) effective transfer     s_eff(d) = s_r * (1 - lambda_r(d) / l_r)
+  (2') with fixed state S_r   s_eff(d) = (s_r - S_r) * (1 - lambda_r(d) / l_r) + S_r
   (3) transfer time          T_xfer = s_eff / B_eff(p, d) + L_tau
   (4) effective bandwidth    B_eff = B_tau * (1 - c_tau) / (1 + n_inflight^tau(p))
   (6) queueing delay         T_queue = max(0, q_d - (beta_max - beta_d)) * t_iter(beta_d)
@@ -33,11 +34,13 @@ def n_blocks(tokens: int) -> int:
 class ModelKVSpec:
     """Per-model constants needed by Eq. (1) and its generalisation.
 
-    For attention models ``state_bytes_per_token`` is the Eq. (1) coefficient
-    (2 * n_layers * n_kv_heads * d_head * b_elem).  For hybrid / SSM models
-    the transferred state has a sequence-length-independent component
+    ``kv_bytes_per_token`` is the Eq. (1) coefficient (2 * layers *
+    n_kv_heads * d_head * b_elem, counting the attention layers alone when
+    ``n_attn_layers`` is set).  For hybrid / SSM models the transferred
+    state has a sequence-length-independent component
     (``fixed_state_bytes``: Mamba SSM + conv state, RWKV WKV + token-shift
-    state) on top of the per-token KV of any attention layers.
+    state) on top of the per-token KV of any attention layers.  A prefix
+    hit covers attention KV only, so Eq. (2') never discounts it.
     """
 
     name: str
@@ -66,12 +69,16 @@ LLAMA3_70B_KV = ModelKVSpec(
 )
 
 
-def effective_transfer_bytes(s_r: float, hit_tokens: float, input_len: int) -> float:
-    """Eq. (2): s_eff = s_r * (1 - lambda/l).  hit_tokens is clamped to [0, l]."""
+def effective_transfer_bytes(s_r: float, hit_tokens: float, input_len: int,
+                             state_bytes: float = 0.0) -> float:
+    """Eq. (2'): s_eff = (s_r - S) * (1 - lambda/l) + S, hit_tokens clamped
+    to [0, l].  ``state_bytes`` S is the part of s_r that is fixed state:
+    the decode side needs it whole whatever its prefix hit.  With S = 0
+    this is Eq. (2) bit for bit (x - 0 = x, y + 0 = y)."""
     if input_len <= 0:
-        return 0.0
+        return float(state_bytes)
     frac = min(max(hit_tokens, 0.0), float(input_len)) / float(input_len)
-    return s_r * (1.0 - frac)
+    return (s_r - state_bytes) * (1.0 - frac) + state_bytes
 
 
 def effective_bandwidth(
@@ -246,9 +253,10 @@ def post_prefill_latency(
     beta_d: int,
     beta_max: int,
     iter_model: IterTimeModel,
+    state_bytes: float = 0.0,
 ) -> float:
     """Eq. (5) objective for one candidate: T_xfer + T_queue + T_decode."""
-    s_eff = effective_transfer_bytes(s_r, hit_tokens, input_len)
+    s_eff = effective_transfer_bytes(s_r, hit_tokens, input_len, state_bytes)
     return (
         transfer_time(s_eff, tier_bw, congestion, n_inflight, tier_latency)
         + queue_time(q_d, beta_d, beta_max, iter_model)

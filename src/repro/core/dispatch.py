@@ -47,7 +47,8 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from ..profiling import span
-from .cost import deflected_cost, effective_bandwidth_tiers, transfer_time
+from .cost import (deflected_cost, effective_bandwidth_tiers,
+                   effective_transfer_bytes, transfer_time)
 from .oracle import OracleView, SelfContentionTracker, TIERS
 from .schedulers import (
     CacheAware,
@@ -62,11 +63,17 @@ from .schedulers import (
     RoundRobin,
     Scheduler,
     _runner_up,
+    v_s_eff,
 )
 from .view import ROLE_DECODE, ClusterView
 
 __all__ = ["CohortItem", "CohortSelector", "DeflectedCohortSelector",
            "supports_cohort"]
+
+# Relative slack of the host's f32 feasibility threshold against the
+# compiled kernel's: 16 f32 epsilons of s_r + threshold, twice a bound of
+# four ulps on the miss fraction plus one rounding each of s_eff and + m_min.
+_F32_SLACK = np.float32(16 * np.finfo(np.float32).eps)
 
 # Exact-type -> scoring shape.  Subclasses of the ladder types are not
 # assumed to keep the parent's op sequence, so membership is by type.
@@ -176,15 +183,10 @@ class CohortSelector:
             if self.H.shape != (R, n):
                 raise ValueError(f"hit_matrix shape {self.H.shape} != {(R, n)}")
 
-            # s_eff as one broadcast: per-element identical to v_s_eff per row
-            # (rows with input_len <= 0 are all-zero there, zeroed here).
-            kv_col = np.array([it.req.kv_bytes for it in self._items],
-                              np.float64)[:, None]
-            l_vec = np.array([it.req.input_len for it in self._items], np.float64)
-            l_col = np.where(l_vec > 0.0, l_vec, 1.0)[:, None]
-            frac = np.minimum(np.maximum(self.H, 0.0), l_col) / l_col
-            self.SE = kv_col * (1.0 - frac)
-            self.SE[l_vec <= 0.0] = 0.0
+            self.SE = np.empty((R, n))
+            for r, it in enumerate(self._items):
+                self.SE[r] = v_s_eff(it.req.kv_bytes, self.H[r],
+                                     it.req.input_len, it.req.state_bytes)
 
             self._dirty = np.zeros(R, bool)
             self._infl_dirty: set[int] = set()
@@ -192,7 +194,8 @@ class CohortSelector:
             self._load = self._loadn = None
             self._tx = None
             self._has_tx = np.zeros(R, bool)
-            self._pl_costs = self._pl_best = self._pl_thr32 = None
+            self._pl_costs = self._pl_best = None
+            self._pl_thr32 = self._pl_band = None
             self._free0 = self._healthy0 = None
 
             if kind in ("la", "ca", "cla"):
@@ -279,7 +282,7 @@ class CohortSelector:
         """Run the cohort-axis kernel once on the snapshot for the serial
         rows; snapshot free/healthy + the kernel's f32 feasibility threshold
         so later rows can prove their precomputed argmin is still live."""
-        from repro.kernels.netkv_score import netkv_score_cohort
+        from repro.kernels.netkv_score import netkv_score_cohort, s_eff_f32
         from repro.kernels.ops import interpret_mode
 
         sched, cv, oracle = self._sched, self._cv, self._oracle
@@ -299,6 +302,7 @@ class CohortSelector:
             [cong[t] for t in TIERS], infl_rows,
             s_r=[it.req.kv_bytes for it in items],
             input_len=[it.req.input_len for it in items],
+            state_bytes=[it.req.state_bytes for it in items],
             iter_a=sched.iter_model.a, iter_b=sched.iter_model.b,
             m_min=sched.m_min, beta_max=sched.beta_max,
             interpret=interpret_mode(),
@@ -312,13 +316,19 @@ class CohortSelector:
                           & (cv.column("role") == ROLE_DECODE)).copy()
         # The kernel masks in f32: replicate its s_eff + m_min threshold so
         # feasibility flips from later reserves are detected in f32 terms.
-        h32 = self.H[rows].astype(np.float32)
-        l32 = np.array([it.req.input_len for it in items],
-                       np.float32)[:, None]
-        s32 = np.array([it.req.kv_bytes for it in items], np.float32)[:, None]
-        hit = np.minimum(h32, l32)
-        se32 = s32 * (np.float32(1.0) - hit / np.maximum(l32, np.float32(1.0)))
+        # Mosaic rounds the kernel's Eq. (2') differently from the host (a
+        # reciprocal for the division, no fused multiply-add), so the
+        # chip's threshold may lie a few ulps off this one: a free value
+        # within ``_pl_band`` of it proves nothing either way.
+        def col(field):
+            return np.array([getattr(it.req, field) for it in items],
+                            np.float32)[:, None]
+
+        s32 = col("kv_bytes")
+        se32 = s_eff_f32(s32, col("state_bytes"), self.H[rows],
+                         col("input_len"))
         self._pl_thr32 = se32 + np.float32(sched.m_min)
+        self._pl_band = _F32_SLACK * (s32 + self._pl_thr32)
 
     # -------------------------------------------------------------- accounting
     def take_setup_time(self) -> float:
@@ -348,10 +358,8 @@ class CohortSelector:
                 if new == self.H[r, slot]:
                     continue
                 self.H[r, slot] = new
-                if req.input_len > 0:
-                    l = float(req.input_len)
-                    self.SE[r, slot] = req.kv_bytes * (
-                        1.0 - min(max(new, 0.0), l) / l)
+                self.SE[r, slot] = effective_transfer_bytes(
+                    req.kv_bytes, new, req.input_len, req.state_bytes)
                 self._dirty[r] = True
 
     # ------------------------------------------------------------------ select
@@ -380,7 +388,7 @@ class CohortSelector:
                     if idx.size > 1 else -1
                 sched._note_decision("rr", req, pid, cv, oracle,
                                      sched._oracle_tier_fn(cv, oracle, pid),
-                                     j, j2, cache=self.H[k])
+                                     j, j2, cache=self.H[k], s_eff=se)
             self._watch_slot(iid)
             return Decision(iid, 0.0, 0.0, oracle.tier_of(pid, iid),
                             float(se[j]))
@@ -393,7 +401,8 @@ class CohortSelector:
                     "la", req, pid, cv, oracle,
                     sched._oracle_tier_fn(cv, oracle, pid),
                     j, _runner_up(idx, ties, (self._load[idx],)),
-                    cost=self._load, cache=self.H[k], load=self._load)
+                    cost=self._load, cache=self.H[k], load=self._load,
+                    s_eff=se)
             self._watch_slot(iid)
             return Decision(iid, float(self._load[j]), 0.0,
                             oracle.tier_of(pid, iid), float(se[j]))
@@ -408,7 +417,8 @@ class CohortSelector:
                     sched._oracle_tier_fn(cv, oracle, pid),
                     j, _runner_up(idx, ties,
                                   (self._load[idx], neg_hit[idx])),
-                    cost=neg_hit, cache=self.H[k], load=self._load)
+                    cost=neg_hit, cache=self.H[k], load=self._load,
+                    s_eff=se)
             self._watch_slot(iid)
             return Decision(iid, float(neg_hit[j]), 0.0,
                             oracle.tier_of(pid, iid), float(se[j]))
@@ -424,7 +434,8 @@ class CohortSelector:
                     "cla", req, pid, cv, oracle,
                     sched._oracle_tier_fn(cv, oracle, pid),
                     j, _runner_up(idx, ties, (score[idx],)),
-                    cost=score, cache=self.H[k], load=self._loadn)
+                    cost=score, cache=self.H[k], load=self._loadn,
+                    s_eff=se)
             self._watch_slot(iid)
             return Decision(iid, float(score[j]), 0.0,
                             oracle.tier_of(pid, iid), float(se[j]))
@@ -453,7 +464,8 @@ class CohortSelector:
                                  lambda jj: int(tier_row[jj]),
                                  j, _runner_up(idx, ties, (cost[idx],)),
                                  cost=cost, cache=self.H[k],
-                                 load=self._t_q + self._t_d, xfer=t_x)
+                                 load=self._t_q + self._t_d, xfer=t_x,
+                                 s_eff=se)
         iid = int(cv.ids[j])
         self._watch_slot(iid)
         return Decision(iid, float(cost[j]), float(t_x[j]), best_tier,
@@ -473,9 +485,11 @@ class CohortSelector:
         if changed.size == 0:
             return True
         thr = self._pl_thr32[i, changed]
+        band = self._pl_band[i, changed]
         f_new = free[changed].astype(np.float32)
         f_old = self._free0[changed].astype(np.float32)
-        return bool(np.all((f_new >= thr) == (f_old >= thr)))
+        clear = (np.abs(f_new - thr) > band) & (np.abs(f_old - thr) > band)
+        return bool(np.all(clear & ((f_new >= thr) == (f_old >= thr))))
 
     def _pallas_row(self, k, req, pid, se, tier_row, infl):
         sched, cv, oracle = self._sched, self._cv, self._oracle

@@ -118,7 +118,10 @@ class NetKVMultiHop(NetKVFull):
         # anywhere; staging competes only for the remainder.
         if self._req_hashes:
             hit = cv.column("hit_tokens")
-            bytes_per_tok = req.kv_bytes / max(req.input_len, 1)
+            # Staging moves attention KV only: the fixed state stays in
+            # s_eff, so the direct leg carries it once.
+            bytes_per_tok = (req.kv_bytes - req.state_bytes) \
+                / max(req.input_len, 1)
             cong = self._congestion_by_tier(oracle)
             n_by = self._n_by_tier(inflight, prefill_id)
             for store in self.stores:

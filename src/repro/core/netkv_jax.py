@@ -73,12 +73,14 @@ def score_pool(
     iter_a: jax.Array,
     iter_b: jax.Array,
     m_min: jax.Array,
+    state_bytes: jax.Array = 0.0,  # scalar f32: S_r, the fixed state in s_r
     *,
     beta_max: int,
 ):
     """Return (costs, best_idx): Eq. (5) per candidate, +inf if infeasible."""
     hit = jnp.minimum(pool.hit_tokens, input_len)
-    s_eff = kv_bytes * (1.0 - hit / jnp.maximum(input_len, 1.0))          # Eq. (2)
+    s_eff = (kv_bytes - state_bytes) * (
+        1.0 - hit / jnp.maximum(input_len, 1.0)) + state_bytes            # Eq. (2')
     beff = (
         tier_bw[pool.tier]
         * (1.0 - congestion[pool.tier])
@@ -100,7 +102,7 @@ def score_pool(
 score_pool_batched = jax.jit(
     jax.vmap(
         score_pool,
-        in_axes=(None, 0, 0, None, None, None, 0, None, None, None),
+        in_axes=(None, 0, 0, None, None, None, 0, None, None, None, 0),
         axis_name="req",
     ),
     static_argnames=("beta_max",),
@@ -118,7 +120,7 @@ class JaxNetKV:
         self.m_min = m_min
 
     def select_arrays(self, pool: PoolArrays, req_kv_bytes, req_len, oracle_view,
-                      n_inflight_by_tier):
+                      n_inflight_by_tier, req_state_bytes=0.0):
         costs, idx = score_pool(
             pool,
             jnp.float32(req_kv_bytes),
@@ -130,6 +132,7 @@ class JaxNetKV:
             jnp.float32(self.iter_model.a),
             jnp.float32(self.iter_model.b),
             jnp.float32(self.m_min),
+            jnp.float32(req_state_bytes),
             beta_max=self.beta_max,
         )
         idx = int(idx)

@@ -51,7 +51,8 @@ class ReferenceScheduler:
         return float(self._rng.random())
 
     def _s_eff(self, req: RequestInfo, cand: CandidateState) -> float:
-        return effective_transfer_bytes(req.kv_bytes, cand.hit_tokens, req.input_len)
+        return effective_transfer_bytes(req.kv_bytes, cand.hit_tokens, req.input_len,
+                                        req.state_bytes)
 
     def feasible(self, req: RequestInfo, cands: Sequence[CandidateState]):
         return [

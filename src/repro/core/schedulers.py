@@ -84,6 +84,7 @@ class RequestInfo:
     kv_bytes: float             # s_r (Eq. 1), aggregate across TP shards
     prefill_remaining: float = 0.0   # s of prefill still to run (streaming)
     tail_bytes: float | None = None  # final-chunk bytes (None = all of s_eff)
+    state_bytes: float = 0.0    # S_r: the part of kv_bytes that is fixed state
 
 
 @dataclasses.dataclass
@@ -123,13 +124,15 @@ def v_iter_time(iter_model: IterTimeModel, beta: np.ndarray) -> np.ndarray:
     return t
 
 
-def v_s_eff(kv_bytes: float, hit_tokens: np.ndarray, input_len: int) -> np.ndarray:
-    """Eq. (2): s_eff = s_r * (1 - lambda/l), hit clamped to [0, l]."""
+def v_s_eff(kv_bytes: float, hit_tokens: np.ndarray, input_len: int,
+            state_bytes: float = 0.0) -> np.ndarray:
+    """Eq. (2'): s_eff = (s_r - S) * (1 - lambda/l) + S, hit clamped to
+    [0, l]; the fixed state S is never discounted (``cost.py``)."""
     if input_len <= 0:
-        return np.zeros_like(hit_tokens)
+        return np.zeros_like(hit_tokens) + state_bytes
     l = float(input_len)
     frac = np.minimum(np.maximum(hit_tokens, 0.0), l) / l
-    return kv_bytes * (1.0 - frac)
+    return (kv_bytes - state_bytes) * (1.0 - frac) + state_bytes
 
 
 def v_transfer_time(
@@ -193,10 +196,12 @@ class Scheduler:
         return self._rng.random(k)
 
     def _note_decision(self, kind, req, prefill_id, cv, oracle, tier_fn,
-                       j, j2, *, cost=None, cache=None, load=None, xfer=None):
+                       j, j2, *, s_eff, cost=None, cache=None, load=None,
+                       xfer=None):
         """Record one sampled forensics row: winner ``j`` vs runner-up
         ``j2`` (full-space indices, -1 = none), components as full-space
-        vectors.  Scalar extraction is synchronous, so reused view scratch
+        vectors, and the winner's s_eff beside the fixed state it carries.
+        Scalar extraction is synchronous, so reused view scratch
         buffers are safe to pass; congestion is read from the *raw* oracle
         snapshot — never ``_congestion_by_tier``, whose predictive
         override advances an EWMA per call."""
@@ -216,7 +221,7 @@ class Scheduler:
             int(cv.ids[j]), int(cv.ids[j2]) if j2 >= 0 else -1,
             tier_w, tier_r, float(oracle.congestion.get(tier_w, 0.0)),
             cost_w, cost_r, cache_w, cache_r, load_w, load_r,
-            xfer_w, xfer_r)
+            xfer_w, xfer_r, float(s_eff[j]), float(req.state_bytes))
 
     def _oracle_tier_fn(self, cv, oracle, prefill_id):
         return lambda jj: oracle.tier_of(prefill_id, int(cv.ids[jj]))
@@ -229,7 +234,8 @@ class Scheduler:
         with every row decode (no flips) the role term is all-True and the
         mask is bit-identical to the pre-RolePlane two-pool filter.
         """
-        s_eff = v_s_eff(req.kv_bytes, cv.column("hit_tokens"), req.input_len)
+        s_eff = v_s_eff(req.kv_bytes, cv.column("hit_tokens"), req.input_len,
+                        req.state_bytes)
         mask = cv.column("healthy") & (cv.column("role") == ROLE_DECODE) \
             & (cv.column("free_memory") >= s_eff + self.m_min)
         return s_eff, mask
@@ -354,7 +360,8 @@ class RoundRobin(Scheduler):
             j2 = int(idx[ord_ids[(pos + 1) % idx.size]]) if idx.size > 1 else -1
             self._note_decision("rr", req, prefill_id, cv, oracle,
                                 self._oracle_tier_fn(cv, oracle, prefill_id),
-                                j, j2, cache=cv.column("hit_tokens"))
+                                j, j2, cache=cv.column("hit_tokens"),
+                                s_eff=s_eff)
         return Decision(iid, 0.0, 0.0, tier, float(s_eff[j]))
 
 
@@ -380,7 +387,7 @@ class LoadAware(Scheduler):
                                 self._oracle_tier_fn(cv, oracle, prefill_id),
                                 j, _runner_up(idx, ties, (load[idx],)),
                                 cost=load, cache=cv.column("hit_tokens"),
-                                load=load)
+                                load=load, s_eff=s_eff)
         return Decision(iid, float(load[j]), 0.0, tier, float(s_eff[j]))
 
 
@@ -408,7 +415,7 @@ class CacheAware(Scheduler):
                                 j, _runner_up(idx, ties,
                                               (load[idx], neg_hit[idx])),
                                 cost=neg_hit, cache=cv.column("hit_tokens"),
-                                load=load)
+                                load=load, s_eff=s_eff)
         return Decision(iid, float(neg_hit[j]), 0.0, tier, float(s_eff[j]))
 
 
@@ -451,7 +458,7 @@ class CacheLoadAware(Scheduler):
                                 self._oracle_tier_fn(cv, oracle, prefill_id),
                                 j, _runner_up(idx, ties, (score[idx],)),
                                 cost=score, cache=cv.column("hit_tokens"),
-                                load=loadn)
+                                load=loadn, s_eff=s_eff)
         return Decision(iid, float(score[j]), 0.0, tier, float(s_eff[j]))
 
 
@@ -506,7 +513,7 @@ class NetKVFull(Scheduler):
                                 lambda jj: int(tier_row[jj]),
                                 j, _runner_up(idx, ties, (cost[idx],)),
                                 cost=cost, cache=cv.column("hit_tokens"),
-                                load=t_q + t_d, xfer=t_x)
+                                load=t_q + t_d, xfer=t_x, s_eff=s_eff)
         return Decision(int(cv.ids[j]), float(cost[j]), float(t_x[j]),
                         best_tier, float(s_eff[j]))
 
@@ -526,6 +533,7 @@ class NetKVFull(Scheduler):
             [oracle.tier_latency[t] for t in TIERS],
             [cong[t] for t in TIERS], [nfl[t] for t in TIERS],
             s_r=float(req.kv_bytes), input_len=float(req.input_len),
+            state_bytes=float(req.state_bytes),
             iter_a=self.iter_model.a, iter_b=self.iter_model.b,
             m_min=self.m_min, beta_max=self.beta_max,
             interpret=interpret_mode(),
@@ -582,7 +590,8 @@ class NetKVFull(Scheduler):
             lvec[j2] = float(c[j2]) - xfer_r
         self._note_decision(self.name, req, prefill_id, cv, oracle,
                             lambda jj_: int(tier_row[jj_]), j, j2,
-                            cost=c, cache=hit, load=lvec, xfer=xvec)
+                            cost=c, cache=hit, load=lvec, xfer=xvec,
+                            s_eff=s_eff)
 
 
 class NetKVStatic(NetKVFull):

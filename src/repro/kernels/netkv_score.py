@@ -2,12 +2,14 @@
 
 At 1000+ node scale the per-request scheduler scoring (lines 3-13 of
 Alg. 1) runs over thousands of candidates; this kernel fuses Eq. (2)-(7)
-elementwise math with the masked argmin reduction in a single VMEM pass.
+elementwise math (Eq. (2') when part of the request's bytes is fixed
+state) with the masked argmin reduction in a single VMEM pass.
 Tier lookups use a one-hot contraction over the 4 tiers (no gather).
 
 Candidates are padded to a multiple of 128 lanes; padding is masked
 infeasible.  The iter model, m_min, beta_max and the real candidate count
-ride SMEM scalar prefetch; the per-request (s_r, l_r) ride a rowed block.
+ride SMEM scalar prefetch; the per-request (s_r, l_r, S_r) ride a rowed
+block.
 The inputs are packed on the host in NumPy and the compiled program is
 built once per (rows, lanes) shape, so a warmed call compiles nothing.
 """
@@ -34,8 +36,11 @@ _I0 = np.int32(0)
 def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                 tier_bw, tier_lat, congestion, n_inflight,
                 *, s_r: float, input_len: float, iter_a: float, iter_b: float,
-                m_min: float, beta_max: int, interpret: bool = False):
+                m_min: float, beta_max: int, state_bytes: float = 0.0,
+                interpret: bool = False):
     """All candidate arrays are (D,).  Returns (costs (D,), best_idx ()).
+    ``state_bytes`` is the part of ``s_r`` that is fixed state, which no
+    prefix hit discounts (Eq. 2').
 
     Single-row view of :func:`netkv_score_cohort` — one program serves both
     the sequential selector and the cohort dispatch path, which is what makes
@@ -50,7 +55,8 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
         free_mem, queued, batch, hit_rows, tier_rows,
         healthy, iter_scale, tier_bw, tier_lat, congestion, infl_rows,
         s_r=[s_r], input_len=[input_len], iter_a=iter_a, iter_b=iter_b,
-        m_min=m_min, beta_max=beta_max, interpret=interpret,
+        m_min=m_min, beta_max=beta_max, state_bytes=[state_bytes],
+        interpret=interpret,
     )
     return _first_row(costs, best)
 
@@ -58,8 +64,8 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
 def _score_cohort_kernel(scal_ref, nreal_ref, free_ref, queued_ref, batch_ref,
                          hit_ref, tier_ref, healthy_ref, scale_ref, rscal_ref,
                          bw_ref, lat_ref, cong_ref, infl_ref, cost_ref, best_ref):
-    """One grid step per cohort row: Eq. (2)-(7) + masked argmin, with the
-    per-request scalars (s_r, l_r) riding a rowed block — row i is
+    """One grid step per cohort row: Eq. (2')-(7) + masked argmin, with the
+    per-request scalars (s_r, l_r, S_r) riding a rowed block — row i is
     bit-identical to a single-row ``netkv_score`` call on the same snapshot.
     The per-row scalars deliberately arrive as a *block* rather than as
     ``scal_ref[base + program_id]``: a traced gather index changes XLA's
@@ -77,13 +83,14 @@ def _score_cohort_kernel(scal_ref, nreal_ref, free_ref, queued_ref, batch_ref,
     one, zero = f32(1.0), f32(0.0)
     s_r = rscal_ref[0, 0, 0]
     l_r = rscal_ref[0, 0, 1]
+    s_fix = rscal_ref[0, 0, 2]
     iter_a = scal_ref[0]
     iter_b = scal_ref[1]
     m_min = scal_ref[2]
     beta_max = scal_ref[3]
 
     hit = jnp.minimum(hit_ref[0], l_r)
-    s_eff = s_r * (one - hit / jnp.maximum(l_r, one))                    # Eq. (2)
+    s_eff = (s_r - s_fix) * (one - hit / jnp.maximum(l_r, one)) + s_fix  # Eq. (2')
 
     tier = tier_ref[0]
     beff = jnp.zeros_like(s_eff)
@@ -163,8 +170,8 @@ def _operands(flt, ints, rows: int, lanes: int):
     * ``tiers`` (4, 4): tier_bw, tier_lat, congestion, and the scalars
       (iter_a, iter_b, m_min, beta_max);
     * ``hit`` (rows, 1, lanes), and ``tier`` (rows, 1, lanes) in ``ints``;
-    * ``per_row`` (rows, 1, 8): (s_r, input_len, 0, 0) and the row's four
-      in-flight counts;
+    * ``per_row`` (rows, 1, 8): (s_r, input_len, state_bytes, 0) and the
+      row's four in-flight counts;
     * ``n_real`` (1,) int32: D, the lanes that hold candidates.
     """
     n = rows * lanes
@@ -193,7 +200,7 @@ def _first_row(costs, best):
 
 def _prepare(free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
              tier_bw, tier_lat, congestion, infl_rows, s_r, input_len, iter_a,
-             iter_b, m_min, beta_max):
+             iter_b, m_min, beta_max, state_bytes=0.0):
     """(rows, lanes, flt, ints): the program's shape and its two packed
     operand buffers (:func:`_operands`), filled on the host in NumPy: no
     device op runs, and a call moves two arrays.
@@ -221,6 +228,7 @@ def _prepare(free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
     tier[:r, 0, :d] = np.asarray(tier_rows, np.int32)
     per_row[:r, 0, 0] = np.asarray(s_r, f32).reshape(r)
     per_row[:r, 0, 1] = np.asarray(input_len, f32).reshape(r)
+    per_row[:r, 0, 2] = np.asarray(state_bytes, f32).reshape(-1)
     per_row[:r, 0, 4:] = np.asarray(infl_rows, f32).reshape(r, 4)
     if r == 1:
         hit[1], tier[1], per_row[1] = hit[0], tier[0], per_row[0]
@@ -231,15 +239,16 @@ def _prepare(free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
 def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
                        iter_scale, tier_bw, tier_lat, congestion, infl_rows,
                        *, s_r, input_len, iter_a: float, iter_b: float,
-                       m_min: float, beta_max: int, interpret: bool = False,
-                       numpy: bool = False):
+                       m_min: float, beta_max: int, state_bytes=0.0,
+                       interpret: bool = False, numpy: bool = False):
     """Cohort-axis ``netkv_score``: R requests against one D-wide snapshot.
 
     Pool columns (free_mem/queued/batch/healthy/iter_scale) are (D,) and
     shared; ``hit_rows``/``tier_rows`` are (R, D) and ``infl_rows``/``s_r``/
-    ``input_len`` are per-row (self-contention and KV size vary with the
-    prefill source).  Returns (costs (R, D), best (R,)) where row i matches
-    a single-row ``netkv_score`` call bit-for-bit (same f32 op sequence,
+    ``input_len``/``state_bytes`` are per-row (self-contention and KV size
+    vary with the prefill source; a scalar ``state_bytes`` is every row's).
+    Returns (costs (R, D), best (R,)) where row i matches a single-row
+    ``netkv_score`` call bit-for-bit (same f32 op sequence,
     grid-stepped over the cohort axis).  The inputs are packed on the host
     and scored by one compiled program per padded shape
     (:func:`_cohort_program`); a call at a shape seen before compiles
@@ -251,22 +260,36 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
             free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
             tier_bw, tier_lat, congestion, infl_rows, s_r=s_r,
             input_len=input_len, iter_a=iter_a, iter_b=iter_b, m_min=m_min,
-            beta_max=beta_max)
+            beta_max=beta_max, state_bytes=state_bytes)
     with span("score.prepare"):
         r, d = np.shape(hit_rows)
         rows, lanes, flt, ints = _prepare(
             free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
             tier_bw, tier_lat, congestion, infl_rows, s_r, input_len, iter_a,
-            iter_b, m_min, beta_max)
+            iter_b, m_min, beta_max, state_bytes)
     with span("score.call"):
         costs, best = _cohort_program(rows, lanes, interpret)(flt, ints)
         return _trim(costs, best, r, d)
 
 
+def s_eff_f32(s_r, state, hit, l_r):
+    """The kernel's f32 Eq. (2') on the host, for (R, 1) ``s_r``, ``state``
+    and ``l_r`` against (R, D) hits, rounded as the interpreted kernel
+    rounds it: XLA:CPU contracts ``kv * miss + state`` into one multiply-add,
+    and the product of two f32 is exact in f64, so one f64 add and a cast to
+    f32 round as it does.  With zero state this is the f32 product
+    ``s_r * miss``.  Mosaic on the TPU multiplies and adds apart and divides
+    through a reciprocal, so the chip's values may differ by a few ulps."""
+    f32 = np.float32
+    hit = np.minimum(np.asarray(hit, f32), l_r)
+    miss = f32(1.0) - hit / np.maximum(l_r, f32(1.0))
+    return ((s_r - state).astype(np.float64) * miss + state).astype(f32)
+
+
 def _netkv_score_cohort_np(free_mem, queued, batch, hit_rows, tier_rows,
                            healthy, iter_scale, tier_bw, tier_lat, congestion,
                            infl_rows, *, s_r, input_len, iter_a, iter_b,
-                           m_min, beta_max):
+                           m_min, beta_max, state_bytes=0.0):
     """f32 NumPy twin of the cohort kernel (same op order, no XLA)."""
     f32 = np.float32
     d = free_mem.shape[0]
@@ -283,11 +306,11 @@ def _netkv_score_cohort_np(free_mem, queued, batch, hit_rows, tier_rows,
     infl = np.asarray(infl_rows, f32)
     s_rv = np.asarray(s_r, f32)[:, None]
     l_rv = np.asarray(input_len, f32)[:, None]
+    s_fix = np.asarray(state_bytes, f32).reshape(-1)[:, None]
     a, b = f32(iter_a), f32(iter_b)
     mm, bm = f32(m_min), f32(float(beta_max))
 
-    hit = np.minimum(hit_rows, l_rv)
-    s_eff = s_rv * (f32(1.0) - hit / np.maximum(l_rv, f32(1.0)))
+    s_eff = s_eff_f32(s_rv, s_fix, hit_rows, l_rv)
     beff = np.zeros_like(s_eff)
     lat = np.zeros_like(s_eff)
     for t in range(4):

@@ -52,25 +52,27 @@ def kv_unpack(pool, buf, block_table, *, force_reference: bool = False):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "s_r", "input_len", "iter_a", "iter_b", "m_min", "beta_max"))
+    "s_r", "input_len", "iter_a", "iter_b", "m_min", "beta_max", "state_bytes"))
 def _netkv_score_ref(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                      tier_bw, tier_lat, congestion, n_inflight, *,
-                     s_r, input_len, iter_a, iter_b, m_min, beta_max):
+                     s_r, input_len, iter_a, iter_b, m_min, beta_max,
+                     state_bytes):
     return _ref.netkv_score_ref(
         free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
         tier_bw, tier_lat, congestion, n_inflight, s_r=s_r,
         input_len=input_len, iter_a=iter_a, iter_b=iter_b, m_min=m_min,
-        beta_max=beta_max)
+        beta_max=beta_max, state_bytes=state_bytes)
 
 
 def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                 tier_bw, tier_lat, congestion, n_inflight, *,
                 s_r: float, input_len: float, iter_a: float, iter_b: float,
-                m_min: float, beta_max: int, force_reference: bool = False):
+                m_min: float, beta_max: int, state_bytes: float = 0.0,
+                force_reference: bool = False):
     """The kernel runs its own program, compiled once per padded shape, so
     request sizes and scalars compile nothing new."""
     kw = dict(s_r=s_r, input_len=input_len, iter_a=iter_a, iter_b=iter_b,
-              m_min=m_min, beta_max=beta_max)
+              m_min=m_min, beta_max=beta_max, state_bytes=state_bytes)
     arrs = (free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
             tier_bw, tier_lat, congestion, n_inflight)
     if force_reference:

@@ -32,11 +32,12 @@ def kv_unpack_ref(pool, buf, block_table):
 
 def netkv_score_ref(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                     tier_bw, tier_lat, congestion, n_inflight,
-                    *, s_r, input_len, iter_a, iter_b, m_min, beta_max):
+                    *, s_r, input_len, iter_a, iter_b, m_min, beta_max,
+                    state_bytes=0.0):
     """Identical arithmetic to repro.core.netkv_jax.score_pool."""
     free_mem = jnp.asarray(free_mem, jnp.float32)
     hit = jnp.minimum(jnp.asarray(hit_tokens, jnp.float32), input_len)
-    s_eff = s_r * (1.0 - hit / max(input_len, 1.0))
+    s_eff = (s_r - state_bytes) * (1.0 - hit / max(input_len, 1.0)) + state_bytes
     tier = jnp.asarray(tier, jnp.int32)
     bw = jnp.asarray(tier_bw, jnp.float32)[tier]
     lat = jnp.asarray(tier_lat, jnp.float32)[tier]

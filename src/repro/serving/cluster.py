@@ -127,7 +127,8 @@ class DisaggregatedCluster:
                     hit_tokens=float(d.hit_pages(req.prompt) * B_TOK),
                     healthy=len(d.free_slots()) > 0,
                 )
-            info = RequestInfo(req.request_id, len(req.prompt), float(pre.kv_bytes))
+            info = RequestInfo(req.request_id, len(req.prompt), float(pre.kv_bytes),
+                               state_bytes=float(pre.state_bytes))
             decision = self.sched.select(info, pe.instance_id, cv, view, self.inflight)
             assert decision is not None, "no feasible decode instance"
             de = next(d for d in self.decode if d.instance_id == decision.instance_id)

@@ -29,6 +29,7 @@ class PrefillResult:
     last_logits: jax.Array
     first_token: int
     kv_bytes: int
+    state_bytes: int = 0         # the part of kv_bytes that ships whole
 
 
 class PrefillEngine:
@@ -46,15 +47,17 @@ class PrefillEngine:
         logits, cache = self._fn(self.params, toks)
         nxt = int(jnp.argmax(logits[0, -1]))
         # Eq. (1): attention KV counts the prompt's tokens, not the padded
-        # cache; fixed-size state counts whole.
+        # cache; fixed-size state counts whole, and is what
+        # ``pack_transfer_chunk`` ships whole with the final chunk.
         n = len(tokens)
-        kv_bytes = sum(
-            (v.size // v.shape[2] * n if _is_kv(k, v) else v.size)
-            * v.dtype.itemsize
-            for k, v in cache.items()
-            if k != "pos" and hasattr(v, "shape")
-        )
-        return PrefillResult(request_id, cache, logits, nxt, kv_bytes)
+        leaves = [(k, v) for k, v in cache.items()
+                  if k != "pos" and hasattr(v, "shape")]
+        state_bytes = sum(v.size * v.dtype.itemsize
+                          for k, v in leaves if not _is_kv(k, v))
+        kv_bytes = sum(v.size // v.shape[2] * n * v.dtype.itemsize
+                       for k, v in leaves if _is_kv(k, v)) + state_bytes
+        return PrefillResult(request_id, cache, logits, nxt, kv_bytes,
+                             state_bytes)
 
 
 def _is_kv(name: str, leaf) -> bool:

@@ -76,6 +76,7 @@ class RequestState:
     decode_instance: int = -1
     tier: int = -1
     s_eff: float = 0.0
+    state_bytes: float = 0.0  # fixed state in s_eff (0: attention-only or deflected)
     hit_tokens: float = 0.0
     transfer_end: float = -1.0
     admit_time: float = -1.0

@@ -358,6 +358,8 @@ class Simulation:
             raise ValueError("chunk_autotune requires chunk_tokens")
         self._chunk_cur = cfg.chunk_tokens
         self._len_ewma = -1.0
+        # Eq. (2'): the part of every request's kv_bytes that is fixed state.
+        self._state_bytes = float(cfg.kv_spec.fixed_state_bytes)
 
     # ---------------------------------------------------------------- trace
     def load_trace(self, trace: Sequence[Request]) -> None:
@@ -428,6 +430,7 @@ class Simulation:
         rs.decode_instance = iid
         rs.tier = 0
         rs.s_eff = 0.0
+        rs.state_bytes = 0.0
         rs.hit_tokens = 0.0
         self.engine.reserve(iid, rs, now)
         self.engine.submit_deflected(iid, rs, now)
@@ -587,9 +590,13 @@ class Simulation:
         """Hand every newly-ready, non-prefix-hit byte to the network.
 
         Cumulative-fraction accounting: after k of the shippable tokens are
-        ready the total streamed bytes equal ``s_eff * k / ship_total``, so
-        per-chunk deltas telescope to *exactly* ``s_eff`` at the last chunk
-        (byte conservation, property-tested across mid-stream rewires).
+        ready the total streamed bytes equal ``kv * k / ship_total``, where
+        ``kv = s_eff - state_bytes`` is the attention KV, so per-chunk deltas
+        telescope to *exactly* ``s_eff`` at the last chunk (byte
+        conservation, property-tested across mid-stream rewires).  The
+        fixed state exists only once the whole prompt is processed: it
+        rides with the final chunk (``pack_transfer_chunk(final=True)``),
+        also when a full prefix hit leaves no KV to stream.
         """
         if rs.s_eff <= 0.0:
             return              # full prefix hit: nothing ever streams
@@ -599,7 +606,12 @@ class Simulation:
         hit = min(rs.hit_tokens, float(l))
         ship_total = float(l) - hit
         shipped = min(max(float(rs.tokens_ready) - hit, 0.0), ship_total)
-        cum = rs.s_eff if last else rs.s_eff * (shipped / ship_total)
+        if last:
+            cum = rs.s_eff
+        elif ship_total > 0.0:
+            cum = (rs.s_eff - rs.state_bytes) * (shipped / ship_total)
+        else:
+            cum = 0.0
         delta = cum - rs.streamed_bytes
         rs.streamed_bytes = cum
         if last:
@@ -650,7 +662,9 @@ class Simulation:
     def _make_info(self, rs: RequestState, streaming: bool,
                    tokens_ready: int = 0) -> RequestInfo:
         req = rs.req
-        info = RequestInfo(req.request_id, req.input_len, rs.kv_bytes)
+        state = self._state_bytes
+        info = RequestInfo(req.request_id, req.input_len, rs.kv_bytes,
+                           state_bytes=state)
         if streaming:
             # Streamed-transfer information set (Eq. 3 extension): bytes
             # keep becoming ready for prefill_remaining more seconds, and
@@ -658,8 +672,9 @@ class Simulation:
             # the ladder's T_xfer column credits the overlap accordingly.
             info.prefill_remaining = self.cfg.prefill_model.c * max(
                 req.input_len - tokens_ready, 0)
-            info.tail_bytes = rs.kv_bytes * (
-                min(self._chunk_eff, req.input_len) / req.input_len)
+            # The final chunk's share of the KV, and the whole fixed state.
+            info.tail_bytes = (rs.kv_bytes - state) * (
+                min(self._chunk_eff, req.input_len) / req.input_len) + state
         return info
 
     def _schedule_one(self, rs: RequestState, now: float,
@@ -799,7 +814,8 @@ class Simulation:
         if not window:
             return
         reqs = [
-            (RequestInfo(rs.req.request_id, rs.req.input_len, rs.kv_bytes), pid)
+            (RequestInfo(rs.req.request_id, rs.req.input_len, rs.kv_bytes,
+                         state_bytes=self._state_bytes), pid)
             for rs, pid in window
         ]
         hit_matrix = np.empty((len(window), self.view.n))
@@ -834,6 +850,7 @@ class Simulation:
         rs.decode_instance = decision.instance_id
         rs.tier = decision.tier
         rs.s_eff = decision.s_eff
+        rs.state_bytes = self._state_bytes
         rs.hit_tokens = self.engine.hit_tokens(decision.instance_id, rs.req)
         self.engine.reserve(decision.instance_id, rs, now)
         rs.stream_scheduled = True
@@ -843,6 +860,7 @@ class Simulation:
         rs.decode_instance = decision.instance_id
         rs.tier = decision.tier
         rs.s_eff = decision.s_eff
+        rs.state_bytes = self._state_bytes
         rs.hit_tokens = self.engine.hit_tokens(decision.instance_id, rs.req)
         self.engine.reserve(decision.instance_id, rs, now)
         src = self._server_of[rs.prefill_instance]
@@ -1116,6 +1134,7 @@ class Simulation:
         rs.admit_time = -1.0
         rs.tier = -1
         rs.s_eff = 0.0
+        rs.state_bytes = 0.0
         rs.hit_tokens = 0.0
         if rs.requeues > 3:
             rs.rejected = True

@@ -22,7 +22,8 @@ Three layers:
    path.
 2. **Decision forensics** — for each (cohort) selection, the winner and
    runner-up candidates' per-component cost breakdown (cache / load /
-   transfer / congestion terms of Eq. (4)/(6)/(7)), captured under a
+   transfer / congestion terms of Eq. (4)/(6)/(7)) and the winner's
+   s_eff beside the fixed state it carries (Eq. 2'), captured under a
    deterministic sampling stride (a call counter, never RNG or wall
    clock, so the sampled set is identical across dispatch modes).
 3. **Exporters** — Chrome/Perfetto trace-event JSON (one track per
@@ -68,6 +69,7 @@ FORENSICS_COLUMNS = (
     "tier_win", "tier_run", "congestion",
     "cost_win", "cost_run", "cache_win", "cache_run",
     "load_win", "load_run", "xfer_win", "xfer_run",
+    "s_eff_win", "state_win",
 )
 
 BREAKDOWN_COLUMNS = (
@@ -164,7 +166,8 @@ class TracePlane:
     def decision(self, kind, request_id, prefill_id, win, run,
                  tier_win, tier_run, congestion,
                  cost_win, cost_run, cache_win, cache_run,
-                 load_win, load_run, xfer_win, xfer_run) -> None:
+                 load_win, load_run, xfer_win, xfer_run,
+                 s_eff_win, state_win) -> None:
         self._dec.append((
             float(self.now), kind, int(request_id), int(prefill_id),
             int(win), int(run), int(tier_win), int(tier_run),
@@ -172,6 +175,7 @@ class TracePlane:
             float(cost_win), float(cost_run), float(cache_win),
             float(cache_run), float(load_win), float(load_run),
             float(xfer_win), float(xfer_run),
+            float(s_eff_win), float(state_win),
         ))
 
     # ------------------------------------------------------------------

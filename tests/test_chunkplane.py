@@ -18,6 +18,7 @@ import math
 import numpy as np
 import pytest
 
+from hybrid_shape import HYBRID_KV, STATE, plain_bytes
 from hypothesis_compat import given, settings, st
 
 from repro.core.cost import (
@@ -65,9 +66,9 @@ def _run(engine, seed=0, duration=5.0, faults=(), rewires=(), **kw):
 def _outcomes(sim):
     return [
         (r.req.request_id, r.prefill_instance, r.prefill_start, r.prefill_end,
-         r.sched_time, r.decode_instance, r.tier, r.s_eff, r.hit_tokens,
-         r.transfer_end, r.admit_time, r.first_token, r.finish, r.tbt,
-         r.tokens_out, r.rejected, r.requeues, r.tokens_ready,
+         r.sched_time, r.decode_instance, r.tier, r.s_eff, r.state_bytes,
+         r.hit_tokens, r.transfer_end, r.admit_time, r.first_token, r.finish,
+         r.tbt, r.tokens_out, r.rejected, r.requeues, r.tokens_ready,
          r.streamed_bytes)
         for r in sim.records
     ]
@@ -83,9 +84,11 @@ class TestChunkedParity:
         assert _outcomes(a) == _outcomes(b)
         assert a.engine.chunks.iterations > len(a.records)  # interleaved
 
-    def test_streaming_bit_exact(self):
-        a = _run("plane", chunk_tokens=512, kv_streaming=True)
-        b = _run("reference", chunk_tokens=512, kv_streaming=True)
+    @pytest.mark.parametrize("kv", [LLAMA3_70B_KV, HYBRID_KV],
+                             ids=["attn", "hybrid"])
+    def test_streaming_bit_exact(self, kv):
+        a = _run("plane", chunk_tokens=512, kv_streaming=True, kv_spec=kv)
+        b = _run("reference", chunk_tokens=512, kv_streaming=True, kv_spec=kv)
         assert _outcomes(a) == _outcomes(b)
         streamed = [r for r in a.records if r.streamed_bytes > 0]
         assert streamed  # the streaming path actually ran
@@ -114,12 +117,51 @@ class TestChunkedParity:
 class TestStreamedBytes:
     """Byte conservation of the streamed transfer path."""
 
-    def test_streamed_bytes_telescope_to_s_eff(self):
-        sim = _run("plane", chunk_tokens=512, kv_streaming=True)
+    @pytest.mark.parametrize("kv", [LLAMA3_70B_KV, HYBRID_KV],
+                             ids=["attn", "hybrid"])
+    def test_streamed_bytes_telescope_to_s_eff(self, kv):
+        sim = _run("plane", chunk_tokens=512, kv_streaming=True, kv_spec=kv)
         done = [r for r in sim.records if r.stream_last]
         assert done
         for r in done:
             assert r.streamed_bytes == pytest.approx(r.s_eff, rel=1e-12)
+            assert r.state_bytes == kv.fixed_state_bytes
+
+    @pytest.mark.parametrize("hit", [0, 2000, 4096, 8192])
+    def test_state_rides_with_the_final_chunk(self, hit):
+        """The KV streams pro rata as its chunks prefill; the fixed state
+        exists only at the prompt's end and ships with the final chunk
+        (as ``pack_transfer_chunk(final=True)`` does), also when a full
+        prefix hit leaves no KV to stream."""
+        sim = Simulation(SimConfig(scheduler="netkv-full", chunk_tokens=512,
+                                   kv_streaming=True, kv_spec=HYBRID_KV,
+                                   **TREE_64))
+        sent = []
+        sim.net.start_transfer = lambda src, dst, n, now, **kw: sent.append(n)
+        sim._reschedule_net = lambda now: None
+        l = 8192
+        req = Request(request_id=0, arrival=0.0, input_len=l, output_len=4,
+                      block_hashes=(), share_group=-1, slo=5.0)
+        ids = sorted(sim._server_of)
+        rs = RequestState(req=req, kv_bytes=float(HYBRID_KV.kv_bytes(l)),
+                          prefill_instance=ids[0], decode_instance=ids[-1],
+                          tier=2, s_eff=plain_bytes(l, hit),
+                          state_bytes=float(STATE), hit_tokens=float(hit))
+        per_chunk = []
+        for ready in range(512, l + 1, 512):
+            rs.tokens_ready = ready
+            n0 = len(sent)
+            sim._stream_chunks(rs, 0.0)
+            per_chunk.append(sum(sent[n0:]))
+        assert rs.stream_last and rs.streamed_bytes == rs.s_eff
+        assert sum(sent) == pytest.approx(rs.s_eff, rel=1e-12)
+        kv = rs.s_eff - STATE
+        assert per_chunk[-1] == pytest.approx(kv * 512 / (l - hit) + STATE
+                                              if hit < l else STATE, rel=1e-12)
+        for k, ready in enumerate(range(512, l, 512)):
+            want = kv * (min(max(ready - hit, 0), l - hit) / (l - hit)) \
+                if hit < l else 0.0
+            assert sum(per_chunk[:k + 1]) == pytest.approx(want, rel=1e-12)
 
     def test_conservation_across_midstream_rewires(self):
         """An OCS rewire mid-stream re-water-fills in-flight chunk flows;

@@ -78,6 +78,49 @@ def test_eq2_bounds(s_r, hit, l):
     assert effective_transfer_bytes(s_r, 0, l) == s_r
 
 
+# Eq. (2'): every copy of s_eff in the program, as f(s_r, hit, l, state).
+def _scalar_s_eff(s_r, hit, l, state):
+    return effective_transfer_bytes(s_r, hit, l, state)
+
+
+def _vector_s_eff(s_r, hit, l, state):
+    from repro.core.schedulers import v_s_eff
+
+    return float(v_s_eff(s_r, np.array([float(hit)]), l, state)[0])
+
+
+S_EFF_COPIES = [_scalar_s_eff, _vector_s_eff]
+
+
+def _eq2_draws(seed, n=64):
+    rng = np.random.default_rng(seed)
+    for _ in range(n):
+        l = int(rng.integers(1, 1 << 17))
+        yield (float(l) * float(rng.choice([6144, 196_608, 327_680])),
+               float(rng.uniform(-0.1, 1.3)) * l, l)
+
+
+@pytest.mark.parametrize("s_eff", S_EFF_COPIES)
+def test_eq2_prime_never_discounts_the_state(s_eff):
+    state = 49_930_240.0
+    for kv, hit, l in _eq2_draws(0):
+        got = s_eff(kv + state, hit, l, state)
+        frac = min(max(hit, 0.0), float(l)) / float(l)
+        assert got == pytest.approx(kv * (1.0 - frac) + state, rel=1e-15)
+        assert state <= got <= kv + state
+        # A full hit leaves exactly the state; no hit leaves all of s_r.
+        assert s_eff(kv + state, l, l, state) == state
+        assert s_eff(kv + state, 0.0, l, state) == kv + state
+
+
+@pytest.mark.parametrize("s_eff", S_EFF_COPIES)
+def test_eq2_prime_zero_state_is_eq2_bit_for_bit(s_eff):
+    for kv, hit, l in _eq2_draws(1):
+        frac = min(max(hit, 0.0), float(l)) / float(l)
+        assert s_eff(kv, hit, l, 0.0) == kv * (1.0 - frac)
+        assert s_eff(kv, hit, l, 0.0) == s_eff(kv, hit, l, 0)
+
+
 @given(
     bw=st.floats(1e6, 1e12),
     c=st.floats(0, 0.99),

@@ -37,6 +37,7 @@ from repro.core.oracle import (
 from repro.sim import FaultEvent, RewireEvent, SimConfig, Simulation
 from repro.traces.mooncake import Request
 
+from hybrid_shape import HYBRID_KV, STATE
 from hypothesis_compat import given, settings, st
 
 LADDER8 = ["rr", "la", "ca", "cla",
@@ -64,15 +65,20 @@ def _pool(n: int, seed: int, tight: bool = False):
     return cands, view
 
 
-def _cohort(r: int, n: int, seed: int, streamed: bool):
+def _cohort(r: int, n: int, seed: int, streamed: bool, state: bool = False):
     """R dispatch-ready requests: random prefix hits (including overshoot
     past input_len, which v_s_eff clips), shared prefill sources, and —
-    when ``streamed`` — a mix of serial / tail-less / tailed rows."""
+    when ``streamed`` — a mix of serial / tail-less / tailed rows; with
+    ``state`` each carries the hybrid shape's fixed state."""
     rng = np.random.default_rng(seed ^ 0x5EED)
     items = []
     for k in range(r):
         l = int(rng.integers(1, 16384))
-        req = RequestInfo(k, l, float(l) * 320 * 1024)
+        if state:
+            req = RequestInfo(k, l, float(HYBRID_KV.kv_bytes(l)),
+                              state_bytes=float(STATE))
+        else:
+            req = RequestInfo(k, l, float(l) * 320 * 1024)
         if streamed and rng.random() < 0.6:
             req.prefill_remaining = float(rng.uniform(0.0, 0.5))
             if rng.random() < 0.5:
@@ -106,9 +112,9 @@ def _run_cohort(sched, cv, view, items, H, infl):
 
 
 def _assert_walk_parity(name, r, n, seed, *, tight=False, streamed=False,
-                        backend=None):
+                        backend=None, state=False):
     cands, view = _pool(n, seed, tight)
-    items, H = _cohort(r, n, seed, streamed)
+    items, H = _cohort(r, n, seed, streamed, state)
     kw = {"backend": backend} if backend else {}
     results, state = [], []
     for runner in (_run_sequential, _run_cohort):
@@ -129,13 +135,16 @@ def _assert_walk_parity(name, r, n, seed, *, tight=False, streamed=False,
 
 
 class TestCohortWalkParity:
+    @pytest.mark.parametrize("state", [False, True])
     @pytest.mark.parametrize("name", LADDER8)
-    def test_serial_cohort(self, name):
-        _assert_walk_parity(name, r=9, n=48, seed=1)
+    def test_serial_cohort(self, name, state):
+        _assert_walk_parity(name, r=9, n=48, seed=1, state=state)
 
+    @pytest.mark.parametrize("state", [False, True])
     @pytest.mark.parametrize("name", ["netkv-full", "netkv-pred"])
-    def test_streamed_cohort(self, name):
-        _assert_walk_parity(name, r=9, n=48, seed=2, streamed=True)
+    def test_streamed_cohort(self, name, state):
+        _assert_walk_parity(name, r=9, n=48, seed=2, streamed=True,
+                            state=state)
 
     @pytest.mark.parametrize("name", LADDER8)
     def test_tight_memory_none_rows(self, name):
@@ -166,7 +175,10 @@ class TestCohortWalkParity:
 # --------------------------------------------------------------------------
 # kernel layer
 # --------------------------------------------------------------------------
-def _kernel_args(r: int, n: int, seed: int):
+def _kernel_args(r: int, n: int, seed: int, state: bool = False):
+    """Scorer inputs; with ``state`` every row's s_r also holds a share of
+    fixed state (``state_bytes``), drawn from a generator of its own so the
+    other inputs are the same draws either way."""
     rng = np.random.default_rng(seed)
     pool = dict(
         free_mem=rng.uniform(1e9, 4e11, n).astype(np.float32),
@@ -191,6 +203,10 @@ def _kernel_args(r: int, n: int, seed: int):
         iter_a=H100_TP4_ITER.a, iter_b=H100_TP4_ITER.b,
         m_min=2.0 * 1024**3, beta_max=64,
     )
+    if state:
+        share = np.random.default_rng(seed + 1000).uniform(0.05, 0.95, r)
+        rows["state_bytes"] = [float(np.float32(f * s))
+                               for f, s in zip(share, rows["s_r"])]
     return pool, rows, scal
 
 
@@ -226,18 +242,47 @@ class TestCohortKernel:
         assert np.array_equal(np.asarray(c_k), np.asarray(c_n))
         assert np.array_equal(np.asarray(b_k), np.asarray(b_n))
 
-    def test_pallas_backend_cohort_walk(self):
+    @pytest.mark.parametrize("state", [False, True])
+    def test_pallas_backend_cohort_walk(self, state):
         # The pallas-backed CohortSelector precomputes serial rows through
         # the cohort-axis kernel; the walk must still match the sequential
         # pallas select stream exactly (shared XLA program).
         _assert_walk_parity("netkv-full", r=4, n=24, seed=5,
-                            backend="pallas")
+                            backend="pallas", state=state)
 
     def test_pallas_backend_mixed_streamed(self):
         # Streamed rows bypass the kernel inside one cohort; serial rows
         # around them must keep their precomputed kernel scores valid.
         _assert_walk_parity("netkv-full", r=5, n=24, seed=6, streamed=True,
                             backend="pallas")
+
+    def test_feasibility_proof_needs_clearance(self):
+        # The chip rounds the kernel's threshold differently from the host,
+        # so a free value within the slack of the host's proves nothing and
+        # the row is scored again.
+        from repro.core.dispatch import _F32_SLACK, CohortSelector
+        from repro.core.view import ROLE_DECODE
+
+        class View:
+            def __init__(self, free):
+                self.cols = {"healthy": np.ones(2, bool),
+                             "role": np.full(2, ROLE_DECODE),
+                             "free_memory": np.array([free, 5e9])}
+
+            def column(self, name):
+                return self.cols[name]
+
+        sel = CohortSelector.__new__(CohortSelector)
+        thr = np.float32(3e9)
+        sel._pl_thr32 = np.full((1, 2), thr)
+        sel._pl_band = _F32_SLACK * (np.float32(1e9) + sel._pl_thr32)
+        sel._free0, sel._healthy0 = np.array([5e9, 5e9]), np.ones(2, bool)
+        band = float(sel._pl_band[0, 0])
+        assert 256.0 < band < 1e4            # ulp(3e9) is 256
+        for free, proven in ((5e9, True), (4e9, True), (2e9, False),
+                             (thr + band / 2, False), (thr + 2 * band, True)):
+            sel._cv = View(free)
+            assert sel._pallas_feas_unchanged(0) is proven, free
 
 
 def _compiles() -> int:
@@ -261,11 +306,12 @@ class TestScorerProgram:
     nothing new."""
 
     @pytest.mark.parametrize("field", ["s_r", "input_len", "iter_a", "iter_b",
-                                       "m_min", "beta_max"])
+                                       "m_min", "beta_max", "state_bytes"])
     def test_rows_and_scalars_share_one_program(self, field):
         from repro.kernels.netkv_score import _cohort_program
 
-        pool, rows2, scal = _kernel_args(2, 20, seed=21)
+        pool, rows2, scal = _kernel_args(2, 20, seed=21,
+                                         state=field == "state_bytes")
         rows1 = {k: v[:1] for k, v in rows2.items()}
         _score_cohort(pool, rows1, scal)
         built = _cohort_program.cache_info().currsize
@@ -320,22 +366,43 @@ class TestScorerProgram:
         assert (costs[:, 12:] == np.float32(BIG)).all()
         assert np.array_equal(best, b12)
 
+    @pytest.mark.parametrize("state", [False, True])
     @pytest.mark.parametrize("r", [1, 2, 3, 4, 5])
-    def test_cached_rows_match_numpy_twin(self, r):
+    def test_cached_rows_match_numpy_twin(self, r, state):
         # Bit-for-bit on the data of test_numpy_twin_matches_kernel.  On
         # other draws the interpreter may fuse iter_a + iter_b * x into one
         # multiply-add and differ from the twin by an ulp; the winners agree.
-        pool, rows, scal = _kernel_args(r, 24, seed=12)
+        # Two such ulps can meet in Eq. (5)'s sum, with or without state:
+        # over seeds 0-39 and R 1-5, 3 state-free and 2 state draws read two
+        # ulps.  Seeds 0-7 hold one such state draw (seed 5, R=3).
+        pool, rows, scal = _kernel_args(r, 24, seed=12, state=state)
         for _ in range(2):                     # built, then cached
             c_k, b_k = _score_cohort(pool, rows, scal)
             c_n, b_n = _score_cohort(pool, rows, scal, numpy=True)
             assert np.array_equal(c_k, c_n) and np.array_equal(b_k, b_n)
         for seed in range(8):
-            pool, rows, scal = _kernel_args(r, 24, seed=seed)
+            pool, rows, scal = _kernel_args(r, 24, seed=seed, state=state)
             c_k, b_k = _score_cohort(pool, rows, scal)
             c_n, b_n = _score_cohort(pool, rows, scal, numpy=True)
-            np.testing.assert_array_max_ulp(c_k, c_n, maxulp=1)
+            np.testing.assert_array_max_ulp(c_k, c_n, maxulp=2 if state else 1)
             assert np.array_equal(b_k, b_n), seed
+
+    @pytest.mark.parametrize("numpy", [False, True])
+    @pytest.mark.parametrize("r", [1, 2, 5])
+    def test_zero_state_is_no_state(self, r, numpy):
+        """Eq. (2') with S = 0 is Eq. (2) bit for bit, through the kernel
+        and its NumPy twin; with state every row costs more where its
+        prefix hit is deep, never less."""
+        for seed in range(8):
+            pool, rows, scal = _kernel_args(r, 130, seed=seed)
+            c0, b0 = _score_cohort(pool, rows, scal, numpy=numpy)
+            zero = dict(rows, state_bytes=[0.0] * r)
+            c1, b1 = _score_cohort(pool, zero, scal, numpy=numpy)
+            assert np.array_equal(c0, c1) and np.array_equal(b0, b1)
+            pool, rows_s, scal = _kernel_args(r, 130, seed=seed, state=True)
+            c2, _ = _score_cohort(pool, rows_s, scal, numpy=numpy)
+            live = (c0 < 1e38) & (c2 < 1e38)
+            assert (c2[live] >= c0[live]).all()
 
 
 # --------------------------------------------------------------------------
@@ -372,7 +439,7 @@ def _drive(mode: str, sched: str, trace, seed: int = 3, **kw):
     sim.run(trace, drain=10.0)
     outs = [
         (rs.req.request_id, rs.prefill_instance, rs.decode_instance, rs.tier,
-         rs.s_eff, rs.rejected, rs.requeues, rs.prefill_end,
+         rs.s_eff, rs.state_bytes, rs.rejected, rs.requeues, rs.prefill_end,
          rs.transfer_end, rs.first_token, rs.finish, rs.tokens_out,
          rs.hit_tokens, rs.sched_time)
         for rs in sim.records
@@ -405,17 +472,20 @@ class TestDispatchModeParity:
                            **GPU64, chunk_tokens=512,
                            prefill_token_budget=1024)
 
-    def test_64gpu_streamed_kv(self):
+    @pytest.mark.parametrize("kv", [None, HYBRID_KV], ids=["attn", "hybrid"])
+    def test_64gpu_streamed_kv(self, kv):
         _assert_e2e_parity("netkv-full", trace=_burst_trace(8, 12),
                            **GPU64, chunk_tokens=512,
-                           prefill_token_budget=1024, kv_streaming=True)
+                           prefill_token_budget=1024, kv_streaming=True,
+                           **({"kv_spec": kv} if kv else {}))
 
-    def test_64gpu_faults_and_rewires(self):
+    @pytest.mark.parametrize("kv", [None, HYBRID_KV], ids=["attn", "hybrid"])
+    def test_64gpu_faults_and_rewires(self, kv):
         faults = [FaultEvent(time=1.5, kind="kill_decode", instance_id=4),
                   FaultEvent(time=2.5, kind="add_decode")]
         rewires = [RewireEvent(time=2.0, scale={2: 0.25, 3: 0.25})]
         _assert_e2e_parity("netkv-full", **GPU64, faults=faults,
-                           rewires=rewires)
+                           rewires=rewires, **({"kv_spec": kv} if kv else {}))
 
     def test_64gpu_reference_event_engine(self):
         # Cohorts must also form (and stay bit-exact) on the legacy heap

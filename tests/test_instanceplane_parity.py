@@ -21,6 +21,8 @@ scalar per-row path (small cohorts) and the fused-array path
 import numpy as np
 import pytest
 
+from hybrid_shape import HYBRID_KV
+from repro.core.cost import LLAMA3_70B_KV
 from repro.sim import FaultEvent, SimConfig, Simulation
 from repro.traces import generate_trace, profile_capacity
 
@@ -39,10 +41,11 @@ def _trace(tree_kw, seed, duration=5.0):
     return generate_trace("rag", duration=duration, target_rps=cap, seed=seed)
 
 
-def _run(engine, tree_kw, sched, seed, faults=(), scalar_rows_max=None):
+def _run(engine, tree_kw, sched, seed, faults=(), scalar_rows_max=None,
+         kv_spec=LLAMA3_70B_KV):
     cfg = SimConfig(scheduler=sched, seed=seed, background=0.2,
                     warmup=1.0, measure=3.0, instance_engine=engine,
-                    faults=faults, **tree_kw)
+                    faults=faults, kv_spec=kv_spec, **tree_kw)
     sim = Simulation(cfg)
     if scalar_rows_max is not None and engine == "plane":
         sim.engine.scalar_rows_max = scalar_rows_max
@@ -53,9 +56,9 @@ def _run(engine, tree_kw, sched, seed, faults=(), scalar_rows_max=None):
 def _outcomes(sim):
     recs = [
         (r.req.request_id, r.prefill_instance, r.prefill_start, r.prefill_end,
-         r.sched_time, r.decode_instance, r.tier, r.s_eff, r.hit_tokens,
-         r.transfer_end, r.admit_time, r.first_token, r.finish, r.tbt,
-         r.tokens_out, r.rejected, r.requeues)
+         r.sched_time, r.decode_instance, r.tier, r.s_eff, r.state_bytes,
+         r.hit_tokens, r.transfer_end, r.admit_time, r.first_token, r.finish,
+         r.tbt, r.tokens_out, r.rejected, r.requeues)
         for r in sim.records
     ]
     finish_order = sorted(
@@ -73,10 +76,12 @@ def _assert_parity(a, b):
 
 
 class TestBitExactParity:
+    @pytest.mark.parametrize("kv", [LLAMA3_70B_KV, HYBRID_KV],
+                             ids=["attn", "hybrid"])
     @pytest.mark.parametrize("seed", range(2))
-    def test_netkv_full_64(self, seed):
-        _assert_parity(_run("plane", TREE_64, "netkv-full", seed),
-                       _run("reference", TREE_64, "netkv-full", seed))
+    def test_netkv_full_64(self, seed, kv):
+        _assert_parity(_run("plane", TREE_64, "netkv-full", seed, kv_spec=kv),
+                       _run("reference", TREE_64, "netkv-full", seed, kv_spec=kv))
 
     def test_cla_64(self):
         _assert_parity(_run("plane", TREE_64, "cla", 0),

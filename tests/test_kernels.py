@@ -115,23 +115,36 @@ class TestNetKVScoreKernel:
                                        np.asarray(c_r)[finite], rtol=1e-5)
         assert int(b_k) == int(b_r)
 
-    def test_matches_core_cost_model(self):
-        """Kernel == the scalar cost model (one candidate, exact)."""
+    @pytest.mark.parametrize("force_reference", [False, True])
+    @pytest.mark.parametrize("state", [0.0, 1.5e9])
+    def test_matches_core_cost_model(self, state, force_reference):
+        """Kernel and its jnp oracle == the scalar cost model (one
+        candidate), Eq. (2') with and without fixed state."""
         from repro.core.cost import post_prefill_latency, H100_TP4_ITER
 
         kw = dict(s_r=3.2e9, input_len=8192.0, iter_a=H100_TP4_ITER.a,
-                  iter_b=H100_TP4_ITER.b, m_min=1e9, beta_max=64)
+                  iter_b=H100_TP4_ITER.b, m_min=1e9, beta_max=64,
+                  state_bytes=state)
         c, _ = ops.netkv_score(
             free_mem=[4e11], queued=[3.0], batch=[62.0], hit_tokens=[4096.0],
             tier=[2], healthy=[1.0], iter_scale=[1.0],
             tier_bw=[4.5e11, 1.25e10, 6.25e9, 3.125e9],
             tier_lat=[1e-6, 3e-6, 8e-6, 1.5e-5],
-            congestion=[0, 0, 0.2, 0.3], n_inflight=[0, 0, 1, 0], **kw)
+            congestion=[0, 0, 0.2, 0.3], n_inflight=[0, 0, 1, 0],
+            force_reference=force_reference, **kw)
         expect = post_prefill_latency(
             s_r=3.2e9, hit_tokens=4096, input_len=8192, tier_bw=6.25e9,
             congestion=0.2, n_inflight=1, tier_latency=8e-6, q_d=3, beta_d=62,
-            beta_max=64, iter_model=H100_TP4_ITER)
+            beta_max=64, iter_model=H100_TP4_ITER, state_bytes=state)
         assert abs(float(c[0]) - expect) / expect < 1e-5
+        # The state is never discounted: it adds its whole transfer time.
+        if state:
+            bare = post_prefill_latency(
+                s_r=3.2e9, hit_tokens=4096, input_len=8192, tier_bw=6.25e9,
+                congestion=0.2, n_inflight=1, tier_latency=8e-6, q_d=3,
+                beta_d=62, beta_max=64, iter_model=H100_TP4_ITER)
+            assert expect - bare == pytest.approx(
+                state / 2 / (6.25e9 * 0.8 / 2), rel=1e-9)
 
 
 class TestRWKVScan:

@@ -6,6 +6,8 @@ from repro.core import CandidateState, H100_TP4_ITER, RequestInfo
 from repro.core.multihop import NetKVMultiHop, StagingStore
 from repro.core.oracle import OracleView, PAPER_TIER_BANDWIDTH, PAPER_TIER_LATENCY
 
+from hybrid_shape import HYBRID_KV, STATE
+
 
 def _view(cong=None):
     # prefill 0; decode 1 (tier 2), decode 2 (tier 3); store 100 near decode 2
@@ -19,6 +21,9 @@ def _view(cong=None):
 
 
 REQ = RequestInfo(7, 8192, 8192 * 320 * 1024)
+# A hybrid model's request: a store can stage its KV, never its fixed state.
+HYBRID_REQ = RequestInfo(7, 8192, float(HYBRID_KV.kv_bytes(8192)),
+                         state_bytes=float(STATE))
 HASHES = tuple(("g", 0, j) for j in range(8192 // 16))
 
 
@@ -49,16 +54,19 @@ def test_warm_store_enables_staged_fetch():
     assert plan.staged_bytes > 0 and plan.direct_bytes == 0
 
 
-def test_partial_hit_splits_legs():
+@pytest.mark.parametrize("req", [REQ, HYBRID_REQ], ids=["attn", "hybrid"])
+def test_partial_hit_splits_legs(req):
     store = StagingStore(100, capacity_bytes=1e12)
     store.insert(HASHES[: len(HASHES) // 2])
     s = _sched([store])
     cands = [CandidateState(2, 4e11, 0, 4, 0.0)]
-    d = s.select(REQ, 0, cands, _view())
-    plan = s.plans[REQ.request_id]
+    d = s.select(req, 0, cands, _view())
+    plan = s.plans[req.request_id]
     assert plan.kind == "staged"
     assert plan.staged_bytes > 0 and plan.direct_bytes > 0
-    assert abs(plan.staged_bytes + plan.direct_bytes - REQ.kv_bytes) < 1e-3 * REQ.kv_bytes
+    assert abs(plan.staged_bytes + plan.direct_bytes - req.kv_bytes) < 1e-3 * req.kv_bytes
+    # The store holds half the prompt's KV; the state rides the direct leg.
+    assert plan.staged_bytes == pytest.approx((req.kv_bytes - req.state_bytes) / 2)
 
 
 def test_dram_bandwidth_caps_staged_leg():

@@ -16,6 +16,8 @@ from repro.core.netkv_jax import JaxNetKV, PoolArrays
 from repro.core.batch_assign import NetKVBatch
 from repro.core.oracle import OracleView, PAPER_TIER_BANDWIDTH, PAPER_TIER_LATENCY
 
+from hybrid_shape import HYBRID_KV, STATE
+
 
 def _view(congestion=None):
     tiers = {(0, 1): 2, (0, 2): 3, (0, 3): 3, (0, 4): 2}
@@ -41,6 +43,10 @@ def _cands(**over):
 
 
 REQ = RequestInfo(0, 8192, 8192 * 320 * 1024)
+# The same request with a hybrid model's fixed state in its s_r (Eq. 2').
+HYBRID_REQ = RequestInfo(0, 8192, float(HYBRID_KV.kv_bytes(8192)),
+                         state_bytes=float(STATE))
+REQS = pytest.mark.parametrize("req", [REQ, HYBRID_REQ], ids=["attn", "hybrid"])
 
 
 def _mk(name, **kw):
@@ -156,9 +162,10 @@ class TestLadderInformationOrder:
 
 
 class TestJaxScorerEquivalence:
+    @REQS
     @given(data=st.data())
     @settings(max_examples=30, deadline=None)
-    def test_matches_python_netkv(self, data):
+    def test_matches_python_netkv(self, req, data):
         rng = np.random.default_rng(data.draw(st.integers(0, 10_000)))
         n = data.draw(st.integers(2, 24))
         cands = [
@@ -167,7 +174,7 @@ class TestJaxScorerEquivalence:
                 free_memory=float(rng.uniform(1e9, 4e11)),
                 queued=int(rng.integers(0, 10)),
                 batch_size=int(rng.integers(0, 64)),
-                hit_tokens=float(rng.integers(0, REQ.input_len)),
+                hit_tokens=float(rng.integers(0, req.input_len)),
                 healthy=bool(rng.random() > 0.1),
                 iter_scale=float(rng.uniform(1.0, 2.0)),
             )
@@ -181,12 +188,12 @@ class TestJaxScorerEquivalence:
             congestion={t: float(rng.uniform(0, 0.8)) for t in range(4)},
         )
         py = _mk("netkv-full")
-        d_py = py.select(REQ, 0, cands, view, None)
+        d_py = py.select(req, 0, cands, view, None)
 
         jx = JaxNetKV(H100_TP4_ITER, 64, m_min=1e9)
         pool = PoolArrays.from_candidates(cands, tiers)
-        idx, costs = jx.select_arrays(pool, REQ.kv_bytes, REQ.input_len, view,
-                                      [0, 0, 0, 0])
+        idx, costs = jx.select_arrays(pool, req.kv_bytes, req.input_len, view,
+                                      [0, 0, 0, 0], req.state_bytes)
         if d_py is None:
             assert idx is None
         else:
@@ -196,15 +203,21 @@ class TestJaxScorerEquivalence:
 
 
 class TestBatchAssignment:
-    def test_window_of_one_equals_greedy(self):
+    @REQS
+    def test_window_of_one_equals_greedy(self, req):
         b = NetKVBatch(H100_TP4_ITER, 64, m_min=1e9)
         g = _mk("netkv-full")
-        cands = _cands()
-        d_b = b.select_batch([(REQ, 0)], [cands], _view(), None)[0]
-        d_g = g.select(REQ, 0, _cands(), _view(), None)
+        cands, cands_g = _cands(), _cands()
+        for c in cands + cands_g:
+            c.hit_tokens = 4096.0              # half the prompt on every one
+        d_b = b.select_batch([(req, 0)], [cands], _view(), None)[0]
+        d_g = g.select(req, 0, cands_g, _view(), None)
         # identical candidates tie; both must pick the same-cost (tier) choice
         assert d_b.tier == d_g.tier
         assert abs(d_b.cost - d_g.cost) < 1e-12
+        # Eq. (2'): the hit halves the KV, never the fixed state.
+        kv = req.kv_bytes - req.state_bytes
+        assert d_b.s_eff == d_g.s_eff == kv * 0.5 + req.state_bytes
 
     def test_joint_window_spreads(self):
         """Two same-window requests should not both pile onto one instance

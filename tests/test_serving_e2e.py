@@ -144,6 +144,26 @@ class TestEndToEndServing:
                 return
         pytest.skip("scheduler spread all requests (no repeat instance)")
 
+    def test_hybrid_decision_prices_what_pack_ships(self):
+        """A served hybrid (Jamba: Mamba and attention layers) prices its
+        fixed state whole, Eq. (2'): each decision's s_eff is the bytes
+        ``pack_transfer`` ships at the decision's hit pages."""
+        cfg = dataclasses.replace(get_spec("jamba-v0.1-52b").smoke,
+                                  compute_dtype=jnp.float32)
+        cluster = DisaggregatedCluster(cfg, scheduler="rr", cache_len=128,
+                                       n_decode=1)
+        decisions, select = [], cluster.sched.select
+        cluster.sched.select = lambda *a: decisions.append(select(*a)) \
+            or decisions[-1]
+        rng = np.random.default_rng(4)
+        prefix = rng.integers(0, cfg.vocab_size, size=2 * B_TOK)
+        reqs = [ServeRequest(i, np.concatenate(
+                    [prefix, rng.integers(0, cfg.vocab_size, size=2 * B_TOK)]),
+                    max_new=2, arrival=i * 0.5) for i in range(2)]
+        res = cluster.serve(reqs)
+        assert [r.hit_pages for r in res] == [0, 2]
+        assert [d.s_eff for d in decisions] == [r.transfer_bytes for r in res]
+
     def test_scheduler_ladder_runs_e2e(self, smoke_cfg):
         cfg = smoke_cfg
         rng = np.random.default_rng(2)

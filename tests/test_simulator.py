@@ -7,8 +7,11 @@ import pytest
 from repro.sim import FaultEvent, SimConfig, Simulation, run_sim
 from repro.sim.instances import RequestState
 from repro.sim.kvcache import BlockCache
+from repro.sim.trace import FORENSICS_COLUMNS
 from repro.traces import generate_trace, profile_capacity
 from repro.traces.mooncake import Request
+
+from hybrid_shape import HYBRID_KV, STATE, plain_bytes
 
 
 def _trace(profile="rag", dur=12.0, frac=1.0, seed=0, **kw):
@@ -345,3 +348,33 @@ class TestMeasuredTelemetry:
     def test_unknown_source_rejected(self):
         with pytest.raises(ValueError):
             Simulation(_cfg("netkv-full", telemetry_source="sflow"))
+
+
+class TestHybridStateBytes:
+    """Eq. (2'): a hybrid model's transfers carry the KV its prefix hit
+    leaves and the fixed Mamba-2 state whole, through every scoring
+    backend, and the forensics rows name the state beside s_eff."""
+
+    @pytest.mark.parametrize("backend", ["numpy", "pallas"])
+    def test_every_transfer_is_eq2_prime(self, backend):
+        tr = generate_trace("rag", duration=2.0, target_rps=25.0, seed=5)
+        cfg = _cfg("netkv-full", seed=5, warmup=0.5, measure=1.5,
+                   kv_spec=HYBRID_KV, trace=True,
+                   scheduler_kwargs={"backend": backend})
+        sim = Simulation(cfg)
+        sim.run(tr, drain=40.0)
+        sent: dict[int, float] = {}
+        for kind, req, t0, t1, inst, tier, a, b in sim.trace.spans():
+            if kind == "xfer_seg":
+                sent[req] = sent.get(req, 0.0) + a
+        served = [rs for rs in sim.records if rs.decode_instance >= 0]
+        assert len(served) == len(sim.records) > 20
+        assert any(rs.hit_tokens > 0 for rs in served)
+        for rs in served:
+            want = plain_bytes(rs.req.input_len, rs.hit_tokens)
+            assert rs.s_eff == want and rs.state_bytes == STATE
+            assert sent[rs.req.request_id] == want, rs.req.request_id
+        rows = [dict(zip(FORENSICS_COLUMNS, r))
+                for r in sim.trace.forensics_rows()]
+        assert rows and all(r["state_win"] == STATE <= r["s_eff_win"]
+                            for r in rows)

@@ -128,6 +128,8 @@ class TestForensics:
         assert rows
         for row in rows[:64]:
             r = dict(zip(FORENSICS_COLUMNS, row))
+            # Attention-only KV: no fixed state beside the winner's s_eff.
+            assert r["state_win"] == 0.0 and r["s_eff_win"] >= 0.0
             if r["kind"] != "netkv-full":
                 continue
             assert r["cost_win"] == pytest.approx(

@@ -23,9 +23,15 @@ from repro.core import (
 from repro.core.cost import IterTimeModel
 from repro.core.oracle import OracleView, PAPER_TIER_BANDWIDTH, PAPER_TIER_LATENCY
 
+from hybrid_shape import HYBRID_KV, STATE
+
 LADDER = ["rr", "la", "ca", "cla", "netkv-topo", "netkv-static", "netkv-full",
           "netkv-pred"]
 REQ = RequestInfo(0, 8192, 8192 * 320 * 1024)
+# The same request with a hybrid model's fixed state in its s_r (Eq. 2').
+HYBRID_REQ = RequestInfo(0, 8192, float(HYBRID_KV.kv_bytes(8192)),
+                         state_bytes=float(STATE))
+REQS = pytest.mark.parametrize("req", [REQ, HYBRID_REQ], ids=["attn", "hybrid"])
 # A piecewise iter model exercises the v_iter_time segments too.
 PIECEWISE_ITER = IterTimeModel(a=0.0124, b=1.6e-5, breaks=(32.0,), slopes=(4e-5,))
 
@@ -68,9 +74,10 @@ def _assert_same(d_new, d_ref):
 
 
 class TestLadderParity:
+    @REQS
     @pytest.mark.parametrize("name", LADDER)
     @pytest.mark.parametrize("seed", range(8))
-    def test_seed_sweep_bit_identical(self, name, seed):
+    def test_seed_sweep_bit_identical(self, name, seed, req):
         """Sequential decisions (shared contention state) match bit-for-bit."""
         rng = np.random.default_rng(seed)
         n = int(rng.integers(1, 40))
@@ -81,8 +88,8 @@ class TestLadderParity:
         infl_new, infl_ref = SelfContentionTracker(), SelfContentionTracker()
         for _ in range(4):
             _assert_same(
-                s_new.select(REQ, 0, cands, view, infl_new),
-                s_ref.select(REQ, 0, cands, view, infl_ref),
+                s_new.select(req, 0, cands, view, infl_new),
+                s_ref.select(req, 0, cands, view, infl_ref),
             )
         assert infl_new._counts == infl_ref._counts
 
@@ -134,16 +141,17 @@ class TestLadderParity:
 
 
 class TestPallasBackendParity:
+    @REQS
     @pytest.mark.parametrize("seed", range(4))
-    def test_winner_matches_numpy(self, seed):
+    def test_winner_matches_numpy(self, seed, req):
         rng = np.random.default_rng(seed + 100)
         n = int(rng.integers(4, 48))
         cands = _pool(rng, n)
         view = _oracle(rng, n)
         d_np = make_scheduler("netkv-full", H100_TP4_ITER, 64, m_min=1e9).select(
-            REQ, 0, cands, view, None)
+            req, 0, cands, view, None)
         d_pl = make_scheduler("netkv-full", H100_TP4_ITER, 64, m_min=1e9,
-                              backend="pallas").select(REQ, 0, cands, view, None)
+                              backend="pallas").select(req, 0, cands, view, None)
         if d_np is None:
             assert d_pl is None
             return
