@@ -292,7 +292,7 @@ class CohortSelector:
         tier_rows = np.stack([cv.tier_row(it.prefill_id) for it in items])
         infl_rows = [[sched._n_by_tier(infl, it.prefill_id)[t] for t in TIERS]
                      for it in items]
-        costs, best = netkv_score_cohort(
+        self._pl_costs, self._pl_best = netkv_score_cohort(
             cv.column("free_memory"), cv.column("queued"), cv.column("batch"),
             self.H[rows], tier_rows,
             cv.column("healthy") & (cv.column("role") == ROLE_DECODE),
@@ -308,9 +308,6 @@ class CohortSelector:
             interpret=interpret_mode(),
         )
         self._pl_rows = {int(k): i for i, k in enumerate(rows)}
-        with span("score.readback"):
-            self._pl_costs = np.asarray(costs)
-            self._pl_best = np.asarray(best)
         self._free0 = cv.column("free_memory").copy()
         self._healthy0 = (cv.column("healthy")
                           & (cv.column("role") == ROLE_DECODE)).copy()

@@ -38,14 +38,15 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
                 *, s_r: float, input_len: float, iter_a: float, iter_b: float,
                 m_min: float, beta_max: int, state_bytes: float = 0.0,
                 interpret: bool = False):
-    """All candidate arrays are (D,).  Returns (costs (D,), best_idx ()).
-    ``state_bytes`` is the part of ``s_r`` that is fixed state, which no
-    prefix hit discounts (Eq. 2').
+    """All candidate arrays are (D,).  Returns NumPy (costs (D,), best_idx
+    ()).  ``state_bytes`` is the part of ``s_r`` that is fixed state, which
+    no prefix hit discounts (Eq. 2').
 
     Single-row view of :func:`netkv_score_cohort` — one program serves both
     the sequential selector and the cohort dispatch path, which is what makes
     their costs bit-identical (two differently-shaped XLA programs are free
-    to fuse/FMA differently; one shared program is not).
+    to fuse/FMA differently; one shared program is not).  Row 0 is taken on
+    the host: no device op runs after the program's one read.
     """
     with span("score.prepare"):
         hit_rows = np.asarray(hit_tokens, np.float32).reshape(1, -1)
@@ -58,7 +59,7 @@ def netkv_score(free_mem, queued, batch, hit_tokens, tier, healthy, iter_scale,
         m_min=m_min, beta_max=beta_max, state_bytes=[state_bytes],
         interpret=interpret,
     )
-    return _first_row(costs, best)
+    return costs[0], best[0]
 
 
 def _score_cohort_kernel(scal_ref, nreal_ref, free_ref, queued_ref, batch_ref,
@@ -123,8 +124,10 @@ def _cohort_program(rows: int, lanes: int, interpret: bool):
     """The scorer's compiled program for ``rows`` cohort rows over ``lanes``
     padded candidates, built once per shape.  Its operands are the two
     buffers :func:`_prepare` packs on the host; slicing them apart happens
-    inside the program.  Returns the kernel's own outputs: (rows, 1, lanes)
-    f32 costs and (rows, 1, 1) int32 winners."""
+    inside the program.  The kernel's outputs, (rows, 1, lanes) f32 costs
+    and (rows, 1, 1) int32 winners, leave it as one int32 buffer
+    (:func:`_outputs` takes it apart), so the host reads a call in one
+    transfer."""
     shared = pl.BlockSpec((1, lanes), lambda i, s, n: (_I0, _I0))
     row_blk = pl.BlockSpec((1, 1, lanes), lambda i, s, n: (i, _I0, _I0))
     row4 = pl.BlockSpec((1, 1, 4), lambda i, s, n: (i, _I0, _I0))
@@ -154,9 +157,14 @@ def _cohort_program(rows: int, lanes: int, interpret: bool):
     # found in a device trace.
     def tpu_custom_call(flt, ints):
         pool, tiers, hit, tier, per_row, n_real = _operands(flt, ints, rows, lanes)
-        return score(tiers[3], n_real, pool[0:1], pool[1:2], pool[2:3], hit,
-                     tier, pool[3:4], pool[4:5], per_row[:, :, :4],
-                     tiers[0:1], tiers[1:2], tiers[2:3], per_row[:, :, 4:])
+        costs, best = score(tiers[3], n_real, pool[0:1], pool[1:2], pool[2:3],
+                            hit, tier, pool[3:4], pool[4:5], per_row[:, :, :4],
+                            tiers[0:1], tiers[1:2], tiers[2:3], per_row[:, :, 4:])
+        # The costs ride bit-cast to int32: winners bit-cast to f32 would be
+        # denormals, which the chip may flush.
+        return jnp.concatenate([
+            best.reshape(rows),
+            jax.lax.bitcast_convert_type(costs, jnp.int32).reshape(rows * lanes)])
 
     return jax.jit(tpu_custom_call)
 
@@ -186,16 +194,10 @@ def _operands(flt, ints, rows: int, lanes: int):
             ints[n:n + 1])
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _trim(costs, best, rows: int, d: int):
-    """The program's padded outputs cut to the caller's R rows and D lanes."""
-    return costs[:rows, 0, :d], best[:rows, 0, 0]
-
-
-@jax.jit
-def _first_row(costs, best):
-    """Row 0 of a one-row cohort's outputs, in one dispatch."""
-    return costs[0], best[0]
+def _outputs(buf, rows: int, lanes: int):
+    """(costs (rows, lanes) f32, best (rows,) int32): NumPy views of the
+    program's output buffer read to the host."""
+    return buf[rows:].view(np.float32).reshape(rows, lanes), buf[:rows]
 
 
 def _prepare(free_mem, queued, batch, hit_rows, tier_rows, healthy, iter_scale,
@@ -247,13 +249,15 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
     shared; ``hit_rows``/``tier_rows`` are (R, D) and ``infl_rows``/``s_r``/
     ``input_len``/``state_bytes`` are per-row (self-contention and KV size
     vary with the prefill source; a scalar ``state_bytes`` is every row's).
-    Returns (costs (R, D), best (R,)) where row i matches a single-row
-    ``netkv_score`` call bit-for-bit (same f32 op sequence,
+    Returns NumPy (costs (R, D), best (R,)) where row i matches a
+    single-row ``netkv_score`` call bit-for-bit (same f32 op sequence,
     grid-stepped over the cohort axis).  The inputs are packed on the host
     and scored by one compiled program per padded shape
     (:func:`_cohort_program`); a call at a shape seen before compiles
-    nothing.  ``numpy=True`` routes through the f32 NumPy twin — the
-    fallback when no XLA backend is usable.
+    nothing.  A call is one launch and one blocking read of the padded
+    outputs, which are cut to (R, D) on the host, so indexing the result
+    dispatches nothing.  ``numpy=True`` routes through the f32 NumPy twin —
+    the fallback when no XLA backend is usable.
     """
     if numpy:
         return _netkv_score_cohort_np(
@@ -268,8 +272,10 @@ def netkv_score_cohort(free_mem, queued, batch, hit_rows, tier_rows, healthy,
             tier_bw, tier_lat, congestion, infl_rows, s_r, input_len, iter_a,
             iter_b, m_min, beta_max, state_bytes)
     with span("score.call"):
-        costs, best = _cohort_program(rows, lanes, interpret)(flt, ints)
-        return _trim(costs, best, r, d)
+        out = _cohort_program(rows, lanes, interpret)(flt, ints)
+    with span("score.readback"):
+        costs, best = _outputs(jax.device_get(out), rows, lanes)
+    return costs[:r, :d], best[:r]
 
 
 def s_eff_f32(s_r, state, hit, l_r):

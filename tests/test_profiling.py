@@ -141,10 +141,10 @@ def test_scorer_call_charges_its_backend_compile_once():
     with span("test.select"):
         assert _score_once(r=r) == 0
     assert REGISTRY.ended("score.call") == calls0 + 1
-    # The first call at a new shape compiles the program and the cut of its
-    # padded outputs to (R, D), both charged to the innermost span; the host
-    # preparation compiles nothing.
-    assert REGISTRY.backend_compiles("score.call") == n0 + 2
+    # The first call at a new shape compiles the program alone, charged to
+    # the innermost span; the host preparation and the host-side cut of the
+    # padded outputs to (R, D) compile nothing.
+    assert REGISTRY.backend_compiles("score.call") == n0 + 1
     assert REGISTRY.backend_compiles("score.prepare") == p0
     (lo, hi), = REGISTRY.intervals("test.select")[-1:]
     assert 0 < REGISTRY.compile_seconds("test.select", lo, hi) <= hi - lo
@@ -152,7 +152,7 @@ def test_scorer_call_charges_its_backend_compile_once():
     with span("test.select"):
         assert _score_once(r=r) == 0
     assert REGISTRY.ended("score.call") == calls0 + 2
-    assert REGISTRY.backend_compiles("score.call") == n0 + 2
+    assert REGISTRY.backend_compiles("score.call") == n0 + 1
     assert REGISTRY.backend_compiles("score.prepare") == p0
     (lo, hi), = REGISTRY.intervals("test.select")[-1:]
     assert REGISTRY.compile_seconds("test.select", lo, hi) == 0.0
